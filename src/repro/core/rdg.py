@@ -463,6 +463,7 @@ class RdgStructure:
     def _triangulate_chunks(self, seed: int) -> List[tuple]:
         """(pts, gids, loc, interior simplices, box_lo, box_hi) per
         virtual chunk."""
+        from .. import obs
         from ..kernels.delaunay import batched_delaunay
 
         dim, grid = self.dim, self.grid
@@ -484,6 +485,7 @@ class RdgStructure:
                                              region=regions[v])
                 done[v] = (pts, gids, loc, simplices, box_lo, box_hi)
             if wrapped:
+                obs.event("plan/rdg/qhull_resume", chunks=len(wrapped))
                 pending = [v for v in pending if v not in set(wrapped)]
                 if not pending:
                     break

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.pairdist.ops import pairdist, pad_points
+from ..kernels.pairdist.ops import pad_points
 from ..kernels.pairdist.ref import pairdist_mask_ref
 from .chunking import chunks_per_dim, cube_chunks_for_pe, morton_decode
 from .prng import (PhiloxReplayer, counter_uniform, device_key, fold_in_many,
@@ -338,7 +338,7 @@ def local_cells_for_pe(grid: CellGrid, P: int, pe: int) -> List[Cell]:
 
 def rgg_pe(
     seed: int, n: int, radius: float, P: int, pe: int, dim: int = 2,
-    interpret: bool = True, force_kernel: bool = False, chunk_P: int = 0,
+    chunk_P: int = 0,
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """All edges incident to PE `pe`'s vertices — the per-PE *host loop*.
 
@@ -376,13 +376,6 @@ def rgg_pe(
     padded = jnp.asarray(blocks)
     r2 = radius * radius
 
-    # kernel path: Pallas (TPU / interpret) or the jit'd jnp oracle.
-    # On CPU the interpret-mode kernel is a correctness tool, not a
-    # performance path — benchmarks and generators default to the oracle
-    # there (identical results; kernel equivalence is asserted in tests).
-    import jax as _jax
-    use_ref = _jax.default_backend() == "cpu" and not force_kernel
-
     pairs_a, pairs_b = [], []
     for cell in local:
         ia = index_of[cell]
@@ -402,12 +395,10 @@ def rgg_pe(
     if pairs_a:
         A = padded[jnp.array(pairs_a)]
         B = padded[jnp.array(pairs_b)]
-        if use_ref:
-            fn = jax.jit(jax.vmap(lambda x, y: pairdist_mask_ref(x, y, r2, dim=dim)))
-            masks = fn(A, B)
-        else:
-            masks = jax.vmap(lambda x, y: pairdist(x, y, r2, dim=dim, interpret=interpret))(A, B)
-        masks = np.asarray(masks)
+        # the jitted jnp twin of the pairdist kernel (bit-identical to
+        # the kernel, asserted in tests)
+        fn = jax.jit(jax.vmap(lambda x, y: pairdist_mask_ref(x, y, r2, dim=dim)))
+        masks = np.asarray(fn(A, B))
         for k, (ia, ib) in enumerate(zip(pairs_a, pairs_b)):
             mm = masks[k][: counts[ia], : counts[ib]]
             if ia == ib:

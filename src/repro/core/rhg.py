@@ -29,7 +29,6 @@ import numpy as np
 from ..kernels.hypdist.ops import (
     FEAT,
     cosh_threshold,
-    hypdist,
     pad_features,
     precompute_features,
 )
@@ -290,23 +289,18 @@ def delta_theta(r: np.ndarray, ell: float, R: float) -> np.ndarray:
     return out
 
 
-def _adjacency(q_feat: np.ndarray, c_feat: np.ndarray, cosh_r: float,
-               interpret: bool = True) -> np.ndarray:
-    """Edge mask via the hypdist kernel (padded to 128 blocks).
-
-    On CPU the jit'd jnp oracle is used (bit-identical to the kernel,
-    asserted in tests); the Pallas path runs on TPU / interpret mode."""
+def _adjacency(q_feat: np.ndarray, c_feat: np.ndarray,
+               cosh_r: float) -> np.ndarray:
+    """Edge mask via the jitted jnp twin of the hypdist kernel (padded
+    to 128 blocks; bit-identical to the kernel, asserted in tests)."""
     qp = pad_features(q_feat)
     cp = pad_features(c_feat)
-    if _jax.default_backend() == "cpu":
-        mask = np.asarray(_hyp_ref(qp, cp, cosh_r))
-    else:
-        mask = np.asarray(hypdist(qp, cp, cosh_r, interpret=interpret))
+    mask = np.asarray(_hyp_ref(qp, cp, cosh_r))
     return mask[: len(q_feat), : len(c_feat)].astype(bool)
 
 
 def rhg_pe(
-    params: RHGParams, P: int, pe: int, interpret: bool = True,
+    params: RHGParams, P: int, pe: int,
     batch: int = 512,
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
     """All edges incident to PE `pe`'s vertices, communication-free.
@@ -351,7 +345,7 @@ def rhg_pe(
     # but checked through the same Eq. 9 path so borderline float rounding
     # can never disagree with the oracle/other PEs.
     if plan.n_core > 1 and core_local.any():
-        m = _adjacency(core_feat[core_local], core_feat, coshR, interpret)
+        m = _adjacency(core_feat[core_local], core_feat, coshR)
         emit(m, core_gids[core_local], core_gids)
 
     # ---- queries: local vertices (incl. owned core) vs every region ----
@@ -376,7 +370,7 @@ def rhg_pe(
         if plan.n_core > 0:
             for s in range(0, len(qr), batch):
                 sl = slice(s, s + batch)
-                emit(_adjacency(q_feat_all[sl], core_feat, coshR, interpret), qg[sl], core_gids)
+                emit(_adjacency(q_feat_all[sl], core_feat, coshR), qg[sl], core_gids)
 
         # vs each annulus (inward + outward unified)
         for ann in plan.annuli:
@@ -407,7 +401,7 @@ def rhg_pe(
                     continue
                 c_feat = np.concatenate(cand_feats)
                 c_gid = np.concatenate(cand_gids)
-                emit(_adjacency(q_feat, c_feat, coshR, interpret), qg[sl], c_gid)
+                emit(_adjacency(q_feat, c_feat, coshR), qg[sl], c_gid)
 
     if edges_u:
         e = np.stack([np.concatenate(edges_u), np.concatenate(edges_v)], axis=1)
@@ -792,8 +786,8 @@ def _cell_index(rings: List[List[EngineCell]], ring: int, cell: int) -> int:
     return off + cell
 
 
-def rhg_union(params: RHGParams, P: int, interpret: bool = True) -> np.ndarray:
-    es = [rhg_pe(params, P, pe, interpret)[0] for pe in range(P)]
+def rhg_union(params: RHGParams, P: int) -> np.ndarray:
+    es = [rhg_pe(params, P, pe)[0] for pe in range(P)]
     e = np.concatenate(es, axis=0)
     return np.unique(e, axis=0) if e.size else e.reshape(0, 2)  # repro: allow(no-numpy-unique) test-oracle union (engine dedups by pair ownership)
 

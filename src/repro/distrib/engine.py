@@ -3,10 +3,8 @@
 The paper's headline property — embarrassingly parallel, communication-
 free generation — is realized here as a *table-driven* SPMD program:
 
-1. ``shard_map_compat``: a version-compatible ``shard_map`` shim
-   (``jax.shard_map`` on new JAX, ``jax.experimental.shard_map`` on
-   0.4.x) plus the HLO zero-collective assertion as a reusable
-   invariant (``assert_communication_free``).
+1. The HLO zero-collective assertion as a reusable invariant
+   (``assert_communication_free``).
 
 2. ``ChunkPlan`` / ``PointPlan``: per-PE tables — chunk keys, universes,
    counts, fixed capacities and decode parameters — emitted by the host
@@ -39,7 +37,6 @@ families.
 from __future__ import annotations
 
 import dataclasses
-import inspect
 from dataclasses import dataclass, field
 from typing import Callable, List, Optional, Sequence, Tuple
 
@@ -56,27 +53,6 @@ from ..core.sampling import (
     round_up_capacity,
     sample_wo_replacement,
 )
-
-try:  # JAX >= 0.5 exposes shard_map at the top level
-    _shard_map = jax.shard_map  # type: ignore[attr-defined]
-except AttributeError:  # JAX 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs, check: bool = False):
-    """Version-compatible ``shard_map`` (0.4.x and 0.5+/0.6+).
-
-    Replication checking is off by default: the sampler's bounded
-    ``while_loop`` has no replication rule on 0.4.x (the parameter is
-    ``check_rep`` there, ``check_vma`` on new JAX)."""
-    kwargs = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    params = inspect.signature(_shard_map).parameters
-    if "check_rep" in params:
-        kwargs["check_rep"] = check
-    elif "check_vma" in params:
-        kwargs["check_vma"] = check
-    return _shard_map(f, **kwargs)
-
 
 # --------------------------------------------------------------------------
 # the zero-collective invariant

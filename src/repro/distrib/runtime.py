@@ -46,9 +46,8 @@ On top of the protocol the runtime owns
   per-PE stream order is preserved exactly: grouping the streamed rows
   by PE and concatenating reproduces :func:`run`'s output
   bit-for-bit.  Ragged final waves are padded with masked rows (same
-  static shapes — one compile per program, never a retrace), slab
-  index buffers are donated to the step where the backend supports it,
-  and ``prefetch`` waves are kept in flight so wave ``k+1`` is
+  static shapes — one compile per program, never a retrace), and
+  ``prefetch`` waves are kept in flight so wave ``k+1`` is
   dispatched before the host consumes wave ``k``.
 
 * **plan/execute overlap** (:class:`PlanEmitter`): the cold-start path.
@@ -86,7 +85,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 # the zero-collective check IS analyze's Pass-1 scanner (one
 # implementation for the runtime assertion and the static CI gate)
 from ..analyze.hloscan import assert_communication_free
-from .engine import default_mesh, shard_map_compat
+from .engine import default_mesh
 # host-side tracing only: spans wrap dispatch/consume boundaries on the
 # host — nothing below ever closes over obs inside a jitted program
 from .. import obs
@@ -216,8 +215,9 @@ def executor(plan: PlanProgram, mesh: Mesh):
     def step(*tables):
         return jax.vmap(jax.vmap(one))(*tables)
 
-    fn = jax.jit(shard_map_compat(
-        step, mesh, in_specs=(spec,) * len(arrays), out_specs=(spec, spec)))
+    fn = jax.jit(jax.shard_map(
+        step, mesh=mesh, in_specs=(spec,) * len(arrays), out_specs=(spec, spec),
+        check_vma=False))
     ns = _sharding(mesh)
     inputs = tuple(_put(a, ns) for a in arrays)
     return fn, inputs
@@ -459,10 +459,9 @@ def _wave_fn(plan: PlanProgram, mesh: Mesh, n_tables: int):
         payload, ok = jax.vmap(one)(*rows)
         return payload[None], (ok & v[:, None])[None]
 
-    donate = () if jax.default_backend() == "cpu" else (0, 1)  # slab buffers
-    return jax.jit(shard_map_compat(
-        step, mesh, in_specs=(spec,) * (2 + n_tables), out_specs=(spec, spec)),
-        donate_argnums=donate)
+    return jax.jit(jax.shard_map(
+        step, mesh=mesh, in_specs=(spec,) * (2 + n_tables),
+        out_specs=(spec, spec), check_vma=False))
 
 
 @dataclass(frozen=True)
@@ -614,10 +613,9 @@ def _slab_fn(slot_fn, mesh: Mesh, n_rows: int):
         payload, ok = jax.vmap(slot_fn)(*(r[0] for r in rows))
         return payload[None], (ok & valid[0][:, None])[None]
 
-    donate = () if jax.default_backend() == "cpu" else tuple(range(1 + n_rows))
-    return jax.jit(shard_map_compat(
-        step, mesh, in_specs=(spec,) * (1 + n_rows), out_specs=(spec, spec)),
-        donate_argnums=donate)
+    return jax.jit(jax.shard_map(
+        step, mesh=mesh, in_specs=(spec,) * (1 + n_rows),
+        out_specs=(spec, spec), check_vma=False))
 
 
 def _slab_key(signature: tuple, valid: np.ndarray, rows, mesh: Mesh) -> tuple:

@@ -47,7 +47,6 @@ from .engine import (  # noqa: F401  (re-exported public API)
     run_edges,
     run_pairs,
     run_points,
-    shard_map_compat,
     stream_chunk_edges,
     stream_pair_edges,
     stream_points,

@@ -1,3 +1,8 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels, and the one place that decides how they run."""
+import jax
+
+
+def interpret_mode() -> bool:
+    """Whether a Pallas kernel runs in interpret mode: on the CPU
+    backend only.  On an accelerator every kernel compiles."""
+    return jax.default_backend() == "cpu"

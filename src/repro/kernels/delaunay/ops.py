@@ -2,10 +2,10 @@
 
 :func:`batched_delaunay` is what the RDG plan emitter calls once per
 halo round: every pending chunk's padded point row triangulates in one
-device batch.  On CPU the jitted/vmapped reference is the production
-path (the Pallas interpreter re-traces per call); pass
-``force_kernel=True`` (or run on an accelerator backend) to dispatch
-the ``pallas_call`` harness.
+device batch.  The triangulator is the jitted, vmapped XLA program of
+:mod:`.ref` on every backend.  It is float64 so that its in-sphere test
+is the very Cramer predicate the certificates use; Mosaic has no f64,
+so a Pallas version would need a different (f32) predicate.
 
 Capacities are emitter-derived and static per (padded size, dim)
 bucket, so recompiles stay bounded across halo rounds:
@@ -22,10 +22,8 @@ bucket, so recompiles stay bounded across halo rounds:
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
-from .delaunay import delaunay_call
 from .ref import delaunay_ref
 
 
@@ -53,8 +51,7 @@ def group_size(dim: int) -> int:
     return 4
 
 
-def batched_delaunay(points, counts, *, dim: int, interpret: bool = True,
-                     force_kernel: bool = False):
+def batched_delaunay(points, counts, *, dim: int):
     """Triangulate ``B`` padded point rows in one dispatch.
 
     points: [B, N, d] float64, counts: [B] int.  Returns
@@ -70,12 +67,5 @@ def batched_delaunay(points, counts, *, dim: int, interpret: bool = True,
         raise ValueError(f"points are {d}-dimensional, expected {dim}")
     S = simplex_capacity(N, dim)
     CAV = cavity_capacity(dim)
-    G = group_size(dim)
-    use_ref = jax.default_backend() == "cpu" and not force_kernel
-    if use_ref:
-        simp, alive, ok = delaunay_ref(pts, cnt, dim=dim, num_simplices=S,
-                                       cavity=CAV, group=G)
-        return simp, alive, ok
-    simp, alive, ok = delaunay_call(pts, cnt, dim=dim, num_simplices=S,
-                                    cavity=CAV, group=G, interpret=interpret)
-    return simp, alive.astype(bool), ok.astype(bool)
+    return delaunay_ref(pts, cnt, dim=dim, num_simplices=S, cavity=CAV,
+                        group=group_size(dim))

@@ -1,4 +1,4 @@
-"""Pure-jnp Bowyer-Watson insertion core + the jitted/vmapped reference.
+"""Pure-jnp Bowyer-Watson insertion core + the jitted, vmapped triangulator.
 
 One chunk+halo point set per row, fixed shapes throughout so a whole
 halo round vmaps into a single device dispatch:
@@ -76,39 +76,6 @@ _FACET_IDX = {
 GROUP = 4
 
 
-def _iota(dtype, n):
-    """``arange(n)`` as a traced primitive.  ``jnp.arange`` materialises
-    an eager constant at trace time, which ``pallas_call`` rejects as a
-    captured const; ``broadcasted_iota`` binds inside the kernel (the
-    same idiom as :mod:`repro.kernels.hist`)."""
-    return jax.lax.broadcasted_iota(dtype, (n,), 0)
-
-
-def _facet_idx(dim):
-    """Traced [d+1, d] facet table: row ``k`` lists all vertices but
-    ``k`` in ascending order, i.e. ``j + (j >= k)``."""
-    kk = jax.lax.broadcasted_iota(jnp.int32, (dim + 1, dim), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (dim + 1, dim), 1)
-    return jj + (jj >= kk).astype(jnp.int32)
-
-
-def _super_unit(dim, dtype):
-    """Traced [d+1, d] super-simplex directions, value-identical to the
-    ``_SUPER_UNIT`` table (``sqrt(3.)`` is correctly rounded, so the 2d
-    entries match the literals bit for bit)."""
-    vv = jax.lax.broadcasted_iota(jnp.int32, (dim + 1, dim), 0)
-    cc = jax.lax.broadcasted_iota(jnp.int32, (dim + 1, dim), 1)
-    if dim == 2:
-        r3 = jnp.sqrt(jnp.asarray(3.0, dtype))
-        x = jnp.where(vv == 0, jnp.asarray(0.0, dtype),
-                      jnp.where(vv == 1, -r3, r3))
-        y = jnp.where(vv == 0, jnp.asarray(2.0, dtype),
-                      jnp.asarray(-1.0, dtype))
-        return jnp.where(cc == 0, x, y)
-    return jnp.where((vv == 0) | (vv == cc + 1),
-                     jnp.asarray(1.0, dtype), jnp.asarray(-1.0, dtype))
-
-
 def boundary_capacity(cavity: int, dim: int) -> int:
     """Max boundary facets of a connected cavity of ``cavity`` simplices:
     ``(d+1)*cavity`` facet slots minus the ``2*(cavity-1)`` interior
@@ -132,18 +99,18 @@ def triangulate(pts, cnt, *, dim: int, num_simplices: int, cavity: int,
     F = CAV * (dim + 1)
     W = boundary_capacity(CAV, dim)   # group-wide new-simplex budget
     UC = 3 * CAV                  # union-cavity window for a whole group
-    fidx = _facet_idx(dim)
+    fidx = jnp.asarray(_FACET_IDX[dim], jnp.int32)
     V = N + dim + 1
 
-    valid = _iota(jnp.int32, N) < cnt
+    valid = jnp.arange(N, dtype=jnp.int32) < cnt
     lo = jnp.min(jnp.where(valid[:, None], pts, jnp.inf), axis=0)
     hi = jnp.max(jnp.where(valid[:, None], pts, -jnp.inf), axis=0)
     lo = jnp.where(jnp.isfinite(lo), lo, 0.0)
     hi = jnp.where(jnp.isfinite(hi), hi, 0.0)
     center = 0.5 * (lo + hi)
     extent = 0.5 * jnp.max(hi - lo) + 1.0
-    sup = center[None, :] + _SUPER_SCALE * extent * _super_unit(
-        dim, pts.dtype)
+    sup = center[None, :] + _SUPER_SCALE * extent * jnp.asarray(
+        _SUPER_UNIT[dim], pts.dtype)
     work = jnp.concatenate([pts, sup], axis=0)          # [V, d]
 
     # packed slot row = d+1 vertex ids (exact small ints in f64) + the
@@ -151,7 +118,7 @@ def triangulate(pts, cnt, *, dim: int, num_simplices: int, cavity: int,
     c0, r20, nd0 = circumsphere(sup)
     packed = jnp.zeros((S, 2 * dim + 1), pts.dtype)
     packed = packed.at[0].set(jnp.concatenate(
-        [_iota(pts.dtype, dim + 1) + N, c0]))
+        [jnp.arange(dim + 1, dtype=pts.dtype) + N, c0]))
     rr = jnp.full(S, -jnp.inf, pts.dtype)
     rr = rr.at[0].set(jnp.where(nd0, r20, jnp.inf))
 
@@ -171,7 +138,7 @@ def triangulate(pts, cnt, *, dim: int, num_simplices: int, cavity: int,
         icum = jnp.cumsum((valid & ~ins).astype(cdt))
         rem = (cnt - nins).astype(jnp.int32)
         stride = jnp.maximum(rem // G, 1)
-        ranks = _iota(jnp.int32, G) * stride
+        ranks = jnp.arange(G, dtype=jnp.int32) * stride
         cand = jnp.searchsorted(
             icum, (ranks + 1).astype(icum.dtype)).astype(jnp.int32)
         cm = ranks < rem
@@ -195,18 +162,18 @@ def triangulate(pts, cnt, *, dim: int, num_simplices: int, cavity: int,
         ucum = jnp.cumsum(bany.astype(cdt))
         nu = ucum[-1].astype(jnp.int32)
         uni = jnp.searchsorted(
-            ucum, _iota(cdt, UC) + 1).astype(jnp.int32)
+            ucum, jnp.arange(UC, dtype=cdt) + 1).astype(jnp.int32)
         badu = bad[jnp.clip(uni, 0, S - 1)] \
-            & (_iota(jnp.int32, UC) < nu)[:, None]       # [UC, G]
+            & (jnp.arange(UC, dtype=jnp.int32) < nu)[:, None]  # [UC, G]
         cumu = jnp.cumsum(badu.astype(cdt), axis=0)
         nb = cumu[-1].astype(jnp.int32)                  # [G]
-        cav1 = _iota(cdt, CAV) + 1
+        cav1 = jnp.arange(CAV, dtype=cdt) + 1
         locidx = jax.vmap(
             lambda c: jnp.searchsorted(c, cav1),
             in_axes=1)(cumu).astype(jnp.int32)           # [G, CAV]
         badidx = jnp.where(locidx < UC,
                            uni[jnp.clip(locidx, 0, UC - 1)], S)
-        cmask = _iota(jnp.int32, CAV)[None, :] < nb[:, None]
+        cmask = jnp.arange(CAV, dtype=jnp.int32)[None, :] < nb[:, None]
         cav = packed[jnp.clip(badidx, 0, S - 1), :dim + 1].astype(jnp.int32)
         facets = jnp.sort(cav[:, :, fidx], axis=-1)      # [G, CAV, d+1, d]
         ffl = facets.reshape(G, F, dim)
@@ -218,7 +185,7 @@ def triangulate(pts, cnt, *, dim: int, num_simplices: int, cavity: int,
         # masked rows get unique sentinel keys so they never pair with
         # (or shadow) a real facet in the occurrence count
         key = jnp.where(fm, key,
-                        ktype(V) ** dim + _iota(ktype, F)[None, :])
+                        ktype(V) ** dim + jnp.arange(F, dtype=ktype)[None, :])
         sk = jnp.sort(key, axis=1)
         # a key is a boundary facet iff it occurs exactly once: the
         # entry after its first sorted occurrence differs
@@ -251,8 +218,8 @@ def triangulate(pts, cnt, *, dim: int, num_simplices: int, cavity: int,
         wcum = jnp.cumsum(wflat.astype(cdt))
         nw = wcum[-1].astype(jnp.int32)
         wsel = jnp.searchsorted(
-            wcum, _iota(cdt, W) + 1).astype(jnp.int32)
-        wm = _iota(jnp.int32, W) < nw
+            wcum, jnp.arange(W, dtype=cdt) + 1).astype(jnp.int32)
+        wm = jnp.arange(W, dtype=jnp.int32) < nw
         wsafe = jnp.clip(wsel, 0, G * F - 1)
         wowner = wsafe // F                              # candidate index
         lpos = (jnp.take(bcum.reshape(G * F), wsafe) - 1).astype(jnp.int32)
@@ -266,7 +233,7 @@ def triangulate(pts, cnt, *, dim: int, num_simplices: int, cavity: int,
         # earlier survivor; removals only weaken stage-1 constraints,
         # so the greedy chain stays valid
         pw = jnp.sum((wctr[:, None, :] - p[None, :, :]) ** 2, axis=2)
-        oh = ((wowner[:, None] == _iota(jnp.int32, G)[None, :])
+        oh = ((wowner[:, None] == jnp.arange(G, dtype=jnp.int32)[None, :])
               & wm[:, None]).astype(jnp.int32)           # [W, G] owner 1-hot
         hg = (oh.T @ (pw < wr2[:, None]).astype(jnp.int32)) > 0
         tg = (oh.T @ (pw == wr2[:, None]).astype(jnp.int32)) > 0
@@ -287,7 +254,7 @@ def triangulate(pts, cnt, *, dim: int, num_simplices: int, cavity: int,
             jnp.where(lpos < nb_o,
                       badidx[wowner, jnp.clip(lpos, 0, CAV - 1)],
                       top + aoff[wowner] + lpos - nb_o),
-            S + _iota(jnp.int32, W))                     # OOB == dropped
+            S + jnp.arange(W, dtype=jnp.int32))  # OOB == dropped
         killed = jnp.any(bad & facc[None, :], axis=1)
         rr = jnp.where(killed, -jnp.inf, rr)  # kill cavities, elementwise
         packed = packed.at[slots].set(
@@ -298,7 +265,7 @@ def triangulate(pts, cnt, *, dim: int, num_simplices: int, cavity: int,
         top = top + jnp.sum(a).astype(top.dtype)
         ins = ins.at[cand].set(facc, mode="drop", unique_indices=True)
         nins = nins + jnp.sum(facc).astype(nins.dtype)
-        gi = _iota(jnp.int32, G)
+        gi = jnp.arange(G, dtype=jnp.int32)
         offdiag = gi[:, None] != gi[None, :]
         ok = (ok
               & (nu <= UC)
@@ -326,7 +293,8 @@ def triangulate(pts, cnt, *, dim: int, num_simplices: int, cavity: int,
                                     "group"))
 def delaunay_ref(pts, cnt, *, dim: int, num_simplices: int, cavity: int,
                  group: int = GROUP):
-    """Jitted reference: vmap of :func:`triangulate` over batch rows.
+    """RDG's triangulator on every backend: vmap of :func:`triangulate`
+    over batch rows.
     pts: [B, N, d] float64, cnt: [B] int32."""
     core = functools.partial(triangulate, dim=dim,
                              num_simplices=num_simplices, cavity=cavity,

@@ -17,14 +17,21 @@ bin are clamped into it (an explicit overflow bin keeps totals exact).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+
+from .. import interpret_mode
 
 # log2 binning: bin 0 holds value 0, bin 1 + k holds [2^k, 2^(k+1)).
 # 32 bins cover every non-negative int32 (max value 2^31 - 1 -> bin 31).
 LOG2_BINS = 32
+
+# block index 0 for the index maps, int32 even under x64
+_ZERO = np.int32(0)
 
 
 def _hist_kernel(v_ref, out_ref, *, num_bins: int, block_b: int, log2: bool):
@@ -34,15 +41,18 @@ def _hist_kernel(v_ref, out_ref, *, num_bins: int, block_b: int, log2: bool):
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
 
+    # every constant is an explicit int32: under x64 a Python int would
+    # trace as int64, which Mosaic cannot lower
+    i32 = jnp.int32
     v = v_ref[:, 0]  # (bv,) int32; negatives = padding
     if log2:
         b = jnp.zeros_like(v)
         for k in range(31):  # static: bin id = 1 + floor(log2 v), exact in int
-            b += (v >= (1 << k)).astype(jnp.int32)
+            b += (v >= i32(1 << k)).astype(i32)
     else:
         b = v
-    b = jnp.where(v < 0, -1, jnp.minimum(b, num_bins - 1))  # clamp = overflow bin
-    local = b - j * block_b  # this step's bin window
+    b = jnp.where(v < i32(0), i32(-1), jnp.minimum(b, i32(num_bins - 1)))
+    local = b - j * i32(block_b)  # this step's bin window
     onehot = local[:, None] == jax.lax.broadcasted_iota(
         jnp.int32, (v.shape[0], block_b), 1)
     out_ref[0, :] += jnp.sum(onehot, axis=0, dtype=jnp.int32)
@@ -58,7 +68,7 @@ def hist_counts(
     log2: bool = False,
     block_v: int = 1024,
     block_b: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """int32 counts[ceil(num_bins/block_b) * block_b] of ``values``.
 
@@ -67,7 +77,8 @@ def hist_counts(
     with ``log2=True`` bin = 0 for value 0, else 1 + floor(log2 value).
     Values >= num_bins land in the last (overflow) bin either way, so
     the counts always sum to the number of non-negative values.  Only
-    the first ``num_bins`` output entries are meaningful.
+    the first ``num_bins`` output entries are meaningful.  ``interpret``
+    defaults to the platform's choice (:func:`repro.kernels.interpret_mode`).
     """
     n, one = values.shape
     assert one == 1 and n % block_v == 0, (values.shape, block_v)
@@ -77,8 +88,8 @@ def hist_counts(
         functools.partial(_hist_kernel, num_bins=num_bins, block_b=block_b,
                           log2=log2),
         grid=grid,
-        in_specs=[pl.BlockSpec((block_v, 1), lambda j, i: (i, 0))],
-        out_specs=pl.BlockSpec((1, block_b), lambda j, i: (0, j)),
+        in_specs=[pl.BlockSpec((block_v, 1), lambda j, i: (i, _ZERO))],
+        out_specs=pl.BlockSpec((1, block_b), lambda j, i: (_ZERO, j)),
         out_shape=jax.ShapeDtypeStruct((1, bpad), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode() if interpret is None else interpret,
     )(values)[0]

@@ -37,17 +37,16 @@ def pad_values(v, block: int = _BLOCK_V) -> jax.Array:
     return out.at[: v.shape[0], 0].set(v)
 
 
-def degree_histogram(values, num_bins: int, *, log2: bool = False,
-                     interpret: bool = True) -> jax.Array:
+def degree_histogram(values, num_bins: int, *,
+                     log2: bool = False) -> jax.Array:
     """int64 counts[num_bins] of ``values`` via the Pallas kernel."""
-    counts = hist_counts(pad_values(values), num_bins=num_bins, log2=log2,
-                         interpret=interpret)
+    counts = hist_counts(pad_values(values), num_bins=num_bins, log2=log2)
     return counts[:num_bins].astype(jnp.int64)
 
 
-def log2_histogram(values, *, interpret: bool = True) -> jax.Array:
+def log2_histogram(values) -> jax.Array:
     """int64 counts[LOG2_BINS]: bin 0 = zeros, bin 1+k = [2^k, 2^(k+1))."""
-    return degree_histogram(values, LOG2_BINS, log2=True, interpret=interpret)
+    return degree_histogram(values, LOG2_BINS, log2=True)
 
 
 @partial(jax.jit, static_argnames=("length",))
@@ -55,7 +54,7 @@ def _scatter_add(ids, length: int):
     return jnp.zeros(length, jnp.int64).at[ids].add(1, mode="drop")
 
 
-def bincount_ids(ids, length: int, *, interpret: bool = True) -> jax.Array:
+def bincount_ids(ids, length: int) -> jax.Array:
     """int64 counts[length]: occurrences of each id in [0, length).
 
     Device scatter-add: the Pallas one-hot kernel when its O(N*length)
@@ -68,7 +67,7 @@ def bincount_ids(ids, length: int, *, interpret: bool = True) -> jax.Array:
     if (length <= SCATTER_BINS_LIMIT
             and ids.size * max(length, 1) <= _ONEHOT_WORK_LIMIT):
         ids = jnp.where(ids >= length, -1, ids)  # drop, don't clamp
-        return degree_histogram(ids, length, interpret=interpret)
+        return degree_histogram(ids, length)
     return _scatter_add(ids, length)
 
 

@@ -21,7 +21,6 @@ def hypdist_mask(
     *,
     block_m: int = 128,
     block_n: int = 128,
-    interpret: bool = True,
 ) -> jax.Array:
     """int8 mask[M, N]: 1 where dist_H(q_i, c_j) < R (Eq. 9 form).
 
@@ -29,4 +28,4 @@ def hypdist_mask(
     Self-pairs are NOT excluded here (gid comparison happens outside).
     """
     return pair_mask(q, c, cosh_r, tile="hyp",
-                     block_m=block_m, block_n=block_n, interpret=interpret)
+                     block_m=block_m, block_n=block_n)

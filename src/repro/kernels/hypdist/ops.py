@@ -1,13 +1,9 @@
-"""Jit'd wrapper: per-vertex precompute + padding + kernel dispatch."""
+"""Per-vertex feature precompute and padding for the hypdist kernel."""
 from __future__ import annotations
 
 import math
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-
-from .hypdist import hypdist_mask
 
 FEAT = 8  # 4 features padded to sublane width
 
@@ -54,7 +50,3 @@ def pad_features(feat: np.ndarray, rows: int | None = None, dtype=np.float64) ->
     out = np.tile(_PAD_ROW, (rows, 1))
     out[:n] = feat
     return out.astype(dtype)
-
-
-def hypdist(q_feat, c_feat, cosh_r, *, interpret: bool = True):
-    return hypdist_mask(jnp.asarray(q_feat), jnp.asarray(c_feat), cosh_r, interpret=interpret)

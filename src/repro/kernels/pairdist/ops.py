@@ -1,10 +1,8 @@
-"""Jit'd public wrapper around the pairdist kernel (pads, dispatches)."""
+"""Point-block padding for the pairdist kernel."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-from .pairdist import pairdist_mask
 
 _DPAD = 8  # sublane-friendly coordinate padding
 
@@ -19,8 +17,3 @@ def pad_points(pts: jax.Array) -> jax.Array:
     npad = (n + 127) // 128 * 128
     out = jnp.full((npad, _DPAD), jnp.inf, jnp.float32)
     return out.at[:n, :d].set(pts.astype(jnp.float32))
-
-
-def pairdist(a_padded, b_padded, r2, *, dim: int, interpret: bool = True):
-    """Adjacency mask between padded point blocks."""
-    return pairdist_mask(a_padded, b_padded, r2, dim=dim, interpret=interpret)

@@ -22,7 +22,6 @@ def pairdist_mask(
     dim: int,
     block_m: int = 128,
     block_n: int = 128,
-    interpret: bool = True,
 ) -> jax.Array:
     """int8 mask[M, N], 1 where ||a_i - b_j||^2 <= r2.
 
@@ -31,4 +30,4 @@ def pairdist_mask(
     `dim` coordinates are used.
     """
     return pair_mask(a, b, r2, tile="euclid", dim=dim,
-                     block_m=block_m, block_n=block_n, interpret=interpret)
+                     block_m=block_m, block_n=block_n)

@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import interpret_mode
+
 TILES = ("euclid", "hyp")
 
 
@@ -51,7 +53,7 @@ def _hyp_tile(q_ref, c_ref, coshr_ref, out_ref):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("tile", "dim", "block_m", "block_n", "interpret")
+    jax.jit, static_argnames=("tile", "dim", "block_m", "block_n")
 )
 def pair_mask(
     a: jax.Array,
@@ -62,7 +64,6 @@ def pair_mask(
     dim: int = 2,
     block_m: int = 128,
     block_n: int = 128,
-    interpret: bool = True,
 ) -> jax.Array:
     """int8 mask[M, N] of the tile test over all (a_i, b_j) pairs.
 
@@ -94,5 +95,5 @@ def pair_mask(
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int8),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(a, b, s)

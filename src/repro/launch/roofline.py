@@ -34,34 +34,37 @@ from .hlocost import HloCost
 
 @dataclass(frozen=True)
 class Peaks:
-    """Peak rates of the executing device(s)."""
-    flops_per_s: float
-    bytes_per_s: float
+    """Published peak rates of one chip."""
+    flops_per_s: float                       # bf16 matrix FLOP/s
+    bytes_per_s: float                       # HBM bytes/s
+    int8_ops_per_s: Optional[float] = None   # int8 matrix ops/s
 
 
-# rough single-device peaks per backend; calibration knobs, not specs —
-# the achieved fraction is for *relative* comparison across programs
-_BACKEND_PEAKS = {
-    "cpu": (5.0e10, 2.0e10),
-    "gpu": (1.0e14, 1.0e12),
-    "tpu": (2.0e14, 8.0e11),
+# per-chip peaks keyed by ``jax.Device.device_kind``.  TPU v5e ("TPU v5
+# lite" to JAX): Google Cloud documentation, "TPU v5e" — 197 TFLOP/s
+# bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s.
+DEVICE_PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(flops_per_s=197e12, bytes_per_s=819e9,
+                         int8_ops_per_s=393e12),
 }
 
 
-def default_peaks() -> Peaks:
-    """Backend-matched peaks; override with ``REPRO_PEAK_FLOPS`` /
-    ``REPRO_PEAK_BW`` (floats, per-second) for calibrated hardware."""
-    f = float(os.environ.get("REPRO_PEAK_FLOPS", 0) or 0)
-    b = float(os.environ.get("REPRO_PEAK_BW", 0) or 0)
-    if f > 0 and b > 0:
-        return Peaks(f, b)
+def peaks_for(device_kind: str) -> Peaks:
+    """The published peaks of ``device_kind``; a kind that is not in
+    :data:`DEVICE_PEAKS` is an error, never a default."""
     try:
-        import jax
-        backend = jax.default_backend()
-    except Exception:
-        backend = "cpu"
-    df, db = _BACKEND_PEAKS.get(backend, _BACKEND_PEAKS["cpu"])
-    return Peaks(f if f > 0 else df, b if b > 0 else db)
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(DEVICE_PEAKS)}") from None
+
+
+def default_peaks() -> Peaks:
+    """Peaks of the default device (:func:`peaks_for` its kind)."""
+    import jax
+
+    return peaks_for(jax.devices()[0].device_kind)
 
 
 def roofline_seconds(flops: float, nbytes: float,

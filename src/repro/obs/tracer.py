@@ -41,6 +41,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
+import jax.profiler
+
 __all__ = [
     "Span", "SpanRecord", "Tracer", "trace", "event", "enable", "disable",
     "is_enabled", "tracer", "capture", "phase_totals", "export_chrome",
@@ -117,9 +119,8 @@ class Span:
         self._id = tr._next_id()
         stack.append(self._id)
         if tr.jax_annotations:
-            self._jax = _jax_annotation(self.name)
-            if self._jax is not None:
-                self._jax.__enter__()
+            self._jax = jax.profiler.TraceAnnotation(self.name)
+            self._jax.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -135,16 +136,6 @@ class Span:
                               threading.get_ident(), self._id, self._parent,
                               self.attrs))
         return False
-
-
-def _jax_annotation(name: str):
-    """A ``jax.profiler.TraceAnnotation`` for ``name``, or None when
-    the bridge is unavailable (jax absent / API moved)."""
-    try:
-        from jax.profiler import TraceAnnotation
-    except Exception:
-        return None
-    return TraceAnnotation(name)
 
 
 class Tracer:
@@ -366,12 +357,6 @@ def capture(jax_annotations: bool = False) -> Iterator[Tracer]:
 def jax_profiler_trace(logdir: str) -> Iterator[None]:
     """Bridge to the JAX device profiler: wraps ``jax.profiler.trace``
     so a traced region also produces a TensorBoard-loadable device
-    profile next to the host-side span trace.  No-op if jax's profiler
-    is unavailable (e.g. headless minimal builds)."""
-    try:
-        from jax.profiler import trace as _jtrace
-    except Exception:
-        yield
-        return
-    with _jtrace(logdir):
+    profile next to the host-side span trace."""
+    with jax.profiler.trace(logdir):
         yield

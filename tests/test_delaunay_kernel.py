@@ -166,53 +166,33 @@ def test_degenerate_certificate_fails_containment():
     assert not np.asarray(nondeg).any()
 
 
-# ------------------------------------------------- pallas-vs-ref parity
-
-@pytest.mark.parametrize("dim", [2, 3])
-def test_pallas_harness_matches_ref(dim):
-    """The pallas_call path (interpret mode on CPU) returns the same
-    simplices/alive/ok as the jitted reference the production dispatch
-    uses."""
-    from repro.kernels.delaunay.delaunay import delaunay_call
-    from repro.kernels.delaunay.ref import delaunay_ref
-
-    pts, counts = _rows(3, B=2, nmax=16, dim=dim)
-    N = pts.shape[1]
-    S, CAV, G = simplex_capacity(N, dim), cavity_capacity(dim), group_size(dim)
-    rs, ra, rk = delaunay_ref(pts, counts, dim=dim, num_simplices=S,
-                              cavity=CAV, group=G)
-    ps, pa, pk = delaunay_call(pts, counts, dim=dim, num_simplices=S,
-                               cavity=CAV, group=G, interpret=True)
-    np.testing.assert_array_equal(np.asarray(rs), np.asarray(ps))
-    np.testing.assert_array_equal(np.asarray(ra), np.asarray(pa).astype(bool))
-    np.testing.assert_array_equal(np.asarray(rk), np.asarray(pk).astype(bool))
-
-
 # --------------------------------------- emitter-level device-DT parity
 
 @pytest.mark.parametrize("P", [1, 2, 8])
 @pytest.mark.parametrize("dim,n", [(2, 512), (3, 128)], ids=["2d", "3d"])
 def test_emitter_device_dt_matches_qhull_oracle(dim, n, P):
-    """End-to-end: the device-DT plan's executed edge set == the per-PE
-    Qhull host-loop union, at P in {1, 2, 8}.  2d n=512 runs the
+    """End-to-end: the device-DT plan's executed edge set at P in
+    {1, 2, 8} == the P=1 Qhull host-loop union.  2d n=512 runs the
     batched-kernel rounds; 3d n=128 wraps the torus and exercises the
     Qhull-resume fallback, so both protocol paths are covered.
 
-    The device edge set is P-invariant at every seed (the chunk grid is
-    P-independent); the *host* union is not quite — Qhull lacks exact
-    predicates, so a near-cocircular quad can flip with the PE's local
-    point set (seed 31 at 2d n=512 P=8 gains one unpaired edge).  Seed
-    29 has no such tie, so equality here is exact; the tolerance-based
-    brute-oracle comparison lives in test_rdg_ba_rmat."""
+    The reference is the P=1 union because the device edge set is
+    P-invariant (the chunk grid is P-independent) while the host union
+    at P > 1 need not be: Qhull lacks exact predicates, so a
+    near-cocircular quad can flip with the PE's local point set (at
+    seed 29, 2d n=512, the P=8 union gains three such edges).  In 2d
+    the count is pinned too: a Delaunay triangulation of n points on
+    the torus has exactly 3n edges (Euler)."""
     from repro.distrib import runtime
 
     seed = 29
     plan = rdg.rdg_pair_plan(seed, n, P, dim)
     payload, valid, _ = runtime.run(plan, check=False)
-    got = set(map(tuple, np.asarray(payload)[
-        np.asarray(valid).astype(bool)].reshape(-1, 2).tolist()))
-    want = set(map(tuple, rdg.rdg_union(seed, n, P, dim).tolist()))
-    assert got == want and len(got) > 0
+    got = np.asarray(payload)[np.asarray(valid).astype(bool)].reshape(-1, 2)
+    want = set(map(tuple, rdg.rdg_union(seed, n, 1, dim).tolist()))
+    assert set(map(tuple, got.tolist())) == want and len(want) > 0
+    if dim == 2:
+        assert len(got) == 3 * n
 
 
 def test_emitter_halo_expansion_on_failed_certification():

@@ -1,4 +1,4 @@
-"""Per-kernel correctness: pallas_call (interpret=True) vs pure-jnp ref,
+"""Per-kernel correctness: pallas_call (interpret mode on the CPU) vs pure-jnp ref,
 swept over shapes and dtypes."""
 import jax
 import jax.numpy as jnp
@@ -17,7 +17,7 @@ def test_pairdist_matches_ref(m, n, dim):
     a = jax.random.uniform(k, (m, 8), dtype=jnp.float32)
     b = jax.random.uniform(jax.random.fold_in(k, 1), (n, 8), dtype=jnp.float32)
     r2 = 0.05
-    got = pairdist_mask(a, b, r2, dim=dim, interpret=True)
+    got = pairdist_mask(a, b, r2, dim=dim)
     want = pairdist_mask_ref(a, b, r2, dim=dim)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -27,7 +27,7 @@ def test_pairdist_block_shapes(block):
     k = jax.random.key(0)
     a = jax.random.uniform(k, (256, 8), dtype=jnp.float32)
     b = jax.random.uniform(jax.random.fold_in(k, 1), (256, 8), dtype=jnp.float32)
-    got = pairdist_mask(a, b, 0.1, dim=2, block_m=block, block_n=block, interpret=True)
+    got = pairdist_mask(a, b, 0.1, dim=2, block_m=block, block_n=block)
     want = pairdist_mask_ref(a, b, 0.1, dim=2)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -36,7 +36,7 @@ def test_pairdist_inf_padding_never_matches():
     pts = jnp.array([[0.1, 0.1], [0.2, 0.2]])
     padded = pad_points(pts)
     assert padded.shape == (128, 8)
-    m = pairdist_mask(padded, padded, 1e9, dim=2, interpret=True)
+    m = pairdist_mask(padded, padded, 1e9, dim=2)
     m = np.asarray(m)
     assert m[:2, :2].all()
     assert not m[2:, :].any() and not m[:, 2:].any()
@@ -45,7 +45,7 @@ def test_pairdist_inf_padding_never_matches():
 def test_pairdist_threshold_is_inclusive():
     a = jnp.zeros((128, 8), jnp.float32)
     b = jnp.zeros((128, 8), jnp.float32).at[:, 0].set(0.5)
-    m = pairdist_mask(a, b, 0.25, dim=2, interpret=True)
+    m = pairdist_mask(a, b, 0.25, dim=2)
     assert np.asarray(m).all()  # dist^2 == r^2 exactly -> edge (<=)
 
 
@@ -82,7 +82,7 @@ def test_pair_mask_tiles_match_shared_ref(tile, m, n):
     """Both geometry kinds are tiles of one kernel: pallas_call output
     == the shared jnp reference for every tile kind."""
     a, b, s = _tile_inputs(tile, m, n)
-    got = pair_mask(a, b, s, tile=tile, dim=2, interpret=True)
+    got = pair_mask(a, b, s, tile=tile, dim=2)
     want = pair_mask_ref(a, b, s, tile=tile, dim=2)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -91,12 +91,12 @@ def test_pair_mask_tiles_match_shared_ref(tile, m, n):
 def test_pair_mask_facades_delegate(tile):
     """pairdist_mask / hypdist_mask are exact facades over pair_mask."""
     a, b, s = _tile_inputs(tile, 128, 128)
-    unified = np.asarray(pair_mask(a, b, s, tile=tile, dim=3, interpret=True))
+    unified = np.asarray(pair_mask(a, b, s, tile=tile, dim=3))
     if tile == "euclid":
-        facade = pairdist_mask(a, b, s, dim=3, interpret=True)
+        facade = pairdist_mask(a, b, s, dim=3)
     else:
         from repro.kernels.hypdist.hypdist import hypdist_mask as _hm
-        facade = _hm(a, b, s, interpret=True)
+        facade = _hm(a, b, s)
     np.testing.assert_array_equal(unified, np.asarray(facade))
 
 
@@ -128,7 +128,7 @@ def test_hypdist_matches_ref(m, n, dtype):
     R = 14.0
     q = _random_features(jax.random.key(m + n), m, R, dtype)
     c = _random_features(jax.random.key(m * n), n, R, dtype)
-    got = hypdist_mask(q, c, np.cosh(R), interpret=True)
+    got = hypdist_mask(q, c, np.cosh(R))
     want = hypdist_mask_ref(q, c, np.cosh(R))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -140,7 +140,7 @@ def test_hypdist_matches_true_hyperbolic_distance():
     r = rng.uniform(0.3 * R, R, n)
     th = rng.uniform(0, 2 * np.pi, n)
     f = pad_features(precompute_features(r, th))
-    got = np.asarray(hypdist_mask(jnp.asarray(f), jnp.asarray(f), np.cosh(R), interpret=True))[:n, :n]
+    got = np.asarray(hypdist_mask(jnp.asarray(f), jnp.asarray(f), np.cosh(R)))[:n, :n]
     arg = (np.cosh(r)[:, None] * np.cosh(r)[None, :]
            - np.sinh(r)[:, None] * np.sinh(r)[None, :] * np.cos(th[:, None] - th[None, :]))
     dist = np.arccosh(np.maximum(arg, 1.0))
@@ -161,7 +161,7 @@ def test_hypdist_padding_rows_never_match():
     with warnings.catch_warnings():
         warnings.simplefilter("error")  # cosh overflow must stay silent
         thr = cosh_threshold(1000.0)
-        m = np.asarray(hypdist_mask(jnp.asarray(p), jnp.asarray(p), thr, interpret=True))
+        m = np.asarray(hypdist_mask(jnp.asarray(p), jnp.asarray(p), thr))
     assert not m[2:, :].any() and not m[:, 2:].any()
 
 
@@ -195,8 +195,8 @@ from repro.kernels.hist.ref import hist_counts_ref, log2_bin_ref
 @pytest.mark.parametrize("log2", [False, True])
 def test_hist_matches_ref(n, num_bins, log2):
     v = np.random.default_rng(n + num_bins).integers(0, 4 * num_bins, n)
-    got = np.asarray(hist_counts(pad_values(v), num_bins=num_bins, log2=log2,
-                                 interpret=True))[:num_bins]
+    got = np.asarray(hist_counts(pad_values(v), num_bins=num_bins,
+                                 log2=log2))[:num_bins]
     want = np.asarray(hist_counts_ref(v, num_bins=num_bins, log2=log2))
     np.testing.assert_array_equal(got, want)
     assert got.sum() == n  # every non-negative value lands in some bin
@@ -206,8 +206,7 @@ def test_hist_matches_ref(n, num_bins, log2):
 def test_hist_block_shapes(block_v, block_b):
     v = np.random.default_rng(0).integers(0, 500, 4096)
     got = np.asarray(hist_counts(pad_values(v, block=block_v), num_bins=500,
-                                 block_v=block_v, block_b=block_b,
-                                 interpret=True))[:500]
+                                 block_v=block_v, block_b=block_b))[:500]
     np.testing.assert_array_equal(got, np.bincount(v, minlength=500))
 
 

@@ -287,8 +287,11 @@ def test_trace_summary_joins_spans_with_programs():
     assert prog["measured_s"] == pytest.approx(out["phases"]["exec_s"])
 
 
-def test_default_peaks_env_override(monkeypatch):
-    monkeypatch.setenv("REPRO_PEAK_FLOPS", "123.0")
-    monkeypatch.setenv("REPRO_PEAK_BW", "456.0")
-    p = roofline.default_peaks()
-    assert p.flops_per_s == 123.0 and p.bytes_per_s == 456.0
+def test_peaks_keyed_by_device_kind():
+    """v5e's published peaks by its JAX device kind; any kind not in
+    the table is an error, never a default."""
+    p = roofline.peaks_for("TPU v5 lite")
+    assert p.flops_per_s == 197e12 and p.int8_ops_per_s == 393e12
+    assert p.bytes_per_s == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.peaks_for("cpu")
