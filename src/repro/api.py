@@ -485,8 +485,8 @@ def plan_emitter(
     Families that implement ``plan_segment(P, lo, hi)`` (e.g.
     :class:`SBM`) emit each PE-range natively at ``(hi - lo) / P`` of
     the full plan cost, so the first segment's waves execute while the
-    background planner emits the rest and time-to-first-chunk drops to
-    ~``max(segment_plan_s, exec_s)``.  Other families fall back to one
+    background planner emits the rest, and the first chunk waits for
+    one segment's plan, not the whole table's.  Other families fall back to one
     full emission *on the planner thread* (first ``build`` call) plus
     ``slice_plan`` segmentation — same ordering/bit-identity contract,
     planning merely moved off the consumer thread.  ``segments=0``
@@ -543,33 +543,31 @@ def iter_edge_chunks(
     ``overlap > 0`` streams through a lazily segmented plan
     (:func:`plan_emitter` with that many segments): plan emission runs
     on a background thread while earlier segments' waves execute, so
-    cold time-to-first-chunk is ~``max(segment_plan_s, exec_s)``
-    instead of ``plan_s + exec_s``.  Chunk edges, PE ids and per-PE
+    the first chunk of a cold stream waits for one segment's plan, not
+    the whole table's.  Chunk edges, PE ids and per-PE
     order are identical to the non-overlapped stream; ``count``
     metadata is omitted (``mask`` stays authoritative).
     """
     if overlap:
-        em = plan_emitter(spec, P, segments=int(overlap), rng_impl=rng_impl)
-        for pe, slots, payload, valid in runtime.stream_slots(
-                em, mesh=mesh, batch=batch, prefetch=prefetch, check=check):
-            if batch <= 1:
-                yield EdgeChunk(buffer=payload[0], mask=valid[0], pe=int(pe))
-            else:
-                yield EdgeChunk(buffer=payload, mask=valid, pe=int(pe))
-        return
-    plan = spec.plan(P, rng_impl=rng_impl)
-    if not isinstance(plan, (engine.ChunkPlan, engine.PairPlan)):
-        raise TypeError(f"unknown plan type {type(plan).__name__}")
-    chunk_counts = plan.count if isinstance(plan, engine.ChunkPlan) else None
+        source = plan_emitter(spec, P, segments=int(overlap),
+                              rng_impl=rng_impl)
+        chunk_counts = None
+    else:
+        source = spec.plan(P, rng_impl=rng_impl)
+        if not isinstance(source, (engine.ChunkPlan, engine.PairPlan)):
+            raise TypeError(f"unknown plan type {type(source).__name__}")
+        chunk_counts = (source.count if isinstance(source, engine.ChunkPlan)
+                        else None)
     for pe, slots, payload, valid in runtime.stream_slots(
-            plan, mesh=mesh, batch=batch, prefetch=prefetch, check=check):
-        count = (int(chunk_counts[pe, slots].sum())
-                 if chunk_counts is not None else None)
-        if batch <= 1:
-            yield EdgeChunk(buffer=payload[0], mask=valid[0],
-                            count=count, pe=int(pe))
-        else:
-            yield EdgeChunk(buffer=payload, mask=valid, count=count, pe=int(pe))
+            source, mesh=mesh, batch=batch, prefetch=prefetch, check=check):
+        with obs.trace("stream/chunk", phase="sink"):
+            count = (int(chunk_counts[pe, slots].sum())
+                     if chunk_counts is not None else None)
+            if batch <= 1:
+                payload, valid = payload[0], valid[0]
+            chunk = EdgeChunk(buffer=payload, mask=valid, count=count,
+                              pe=int(pe))
+        yield chunk
 
 
 def iter_points(

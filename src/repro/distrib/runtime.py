@@ -54,9 +54,10 @@ On top of the protocol the runtime owns
   Plan emission is communication-free too, so a plan can be emitted
   one PE-range segment at a time on a background planner thread while
   the runtime executes the previous segment's waves — mirroring the
-  wave prefetch double-buffering one level up.  Time-to-first-chunk
-  drops from ``plan_s + exec_s`` to roughly ``max(segment_plan_s,
-  exec_s)``; per-PE stream order is preserved exactly.
+  wave prefetch double-buffering one level up.  The first chunk waits
+  for one segment's plan instead of the whole table's, and later
+  segments are planned while earlier ones execute; per-PE stream order
+  is preserved exactly.
 
 * **meshes**: every entry point takes an explicit ``mesh=`` and accepts
   a multi-process ``jax.make_mesh``.  Table and slab inputs are built
@@ -169,6 +170,14 @@ def _consumable(arr):
     return out
 
 
+def _named(step: Callable, name: str) -> Callable:
+    """Give a step function its program's name: ``jax.jit`` calls the
+    program ``jit_<name>``, which is what the device trace's ``XLA
+    Modules`` line shows for each execution."""
+    step.__name__ = step.__qualname__ = name
+    return step
+
+
 def _local_rows(mesh: Mesh) -> np.ndarray:
     """bool [D]: which mesh rows this process can address."""
     pi = jax.process_index()
@@ -216,8 +225,8 @@ def executor(plan: PlanProgram, mesh: Mesh):
         return jax.vmap(jax.vmap(one))(*tables)
 
     fn = jax.jit(jax.shard_map(
-        step, mesh=mesh, in_specs=(spec,) * len(arrays), out_specs=(spec, spec),
-        check_vma=False))
+        _named(step, "run"), mesh=mesh, in_specs=(spec,) * len(arrays),
+        out_specs=(spec, spec), check_vma=False))
     ns = _sharding(mesh)
     inputs = tuple(_put(a, ns) for a in arrays)
     return fn, inputs
@@ -251,9 +260,6 @@ def run(plan: PlanProgram, mesh: Optional[Mesh] = None, check: bool = True,
             ent.checked = True
     with obs.trace("run/exec", phase="exec", mode="run"):
         payload, valid = ent.fn(*inputs)
-        if obs.is_enabled():
-            # measurement mode: attribute device time to this span
-            jax.block_until_ready((payload, valid))
     return payload, valid, hlo
 
 
@@ -273,8 +279,9 @@ def lower_run(plan: PlanProgram, mesh: Optional[Mesh] = None):
 # lazily segmented plans: plan/execute overlap
 # --------------------------------------------------------------------------
 #
-# Cold-start latency of the streaming path is plan_s + exec_s: the full
-# [P, C] table is emitted before the first wave dispatches.  But plan
+# Cold-start latency of the streaming path is the whole plan's time plus
+# the first wave's: the full [P, C] table is emitted before the first
+# wave dispatches.  But plan
 # emission is communication-free too — any PE range's rows are a pure
 # function of (spec, P) — so the table can be emitted *per PE range*,
 # and the range covering the first mesh pass can start executing while
@@ -283,10 +290,10 @@ def lower_run(plan: PlanProgram, mesh: Optional[Mesh] = None):
 # standalone PlanProgram (num_pes == hi - lo), and stream_waves runs a
 # background planner thread feeding segments through a bounded queue —
 # the same double-buffering shape as the wave prefetch deque, one level
-# up.  Time-to-first-chunk drops from plan_s + exec_s to roughly
-# max(segment_plan_s, exec_s); ``plan/overlap`` spans (builder thread)
-# against ``wave/*`` spans (consumer thread) make the pipelining
-# visible in repro.obs traces.
+# up.  The first chunk then waits for one segment's plan, not the whole
+# table's; ``plan/overlap`` spans (builder thread) against ``wave/*``
+# spans (consumer thread) make the pipelining visible in repro.obs
+# traces, and the device trace shows the waves' execution beside them.
 
 #: default number of plan segments when the emitter does not pin one
 DEFAULT_SEGMENTS = 4
@@ -448,7 +455,8 @@ def wave_schedule(plan: PlanProgram, D: int, batch: int = 1) -> WaveSchedule:
 def _wave_fn(plan: PlanProgram, mesh: Mesh, n_tables: int):
     """The jitted shard_map'd wave step: gather each mesh row's next
     ``[B]`` slots from its local table shard, run the slot fn, and mask
-    padding rows out of the validity output."""
+    padding rows out of the validity output.  Named after the plan's
+    kind (``wave_chunk``, ``wave_pair``, ``wave_point``)."""
     spec = PartitionSpec(mesh.axis_names)
     one = plan.slot_fn()
 
@@ -459,9 +467,11 @@ def _wave_fn(plan: PlanProgram, mesh: Mesh, n_tables: int):
         payload, ok = jax.vmap(one)(*rows)
         return payload[None], (ok & v[:, None])[None]
 
+    kind = type(plan).__name__.lower().removesuffix("plan")
     return jax.jit(jax.shard_map(
-        step, mesh=mesh, in_specs=(spec,) * (2 + n_tables),
-        out_specs=(spec, spec), check_vma=False))
+        _named(step, f"wave_{kind}"), mesh=mesh,
+        in_specs=(spec,) * (2 + n_tables), out_specs=(spec, spec),
+        check_vma=False))
 
 
 @dataclass(frozen=True)
@@ -489,7 +499,9 @@ class Wave:
             if row is None:
                 continue
             pe, slots = row
-            yield pe, slots, self.payload[d], self.valid[d]
+            with obs.trace("wave/rows", phase="sink"):
+                payload, valid = self.payload[d], self.valid[d]
+            yield pe, slots, payload, valid
 
 
 def lower_wave(plan: PlanProgram, mesh: Optional[Mesh] = None,
@@ -551,29 +563,24 @@ def stream_waves(
         ws = wave_schedule(plan, D, batch)
     if not ws.num_waves:
         return
-    arrays = plan.input_arrays()
-    key = ("wave", plan.signature(), mesh, ws.batch)
-    ent = _CACHE.get(key)
-    obs.event("compile_cache", kind="wave", hit=ent is not None)
-    if ent is None:
-        fn = _wave_fn(plan, mesh, len(arrays))
-        ent = _CACHE[key] = _Entry(fn, _sharding(mesh))
-    ns = ent.sharding
-    tables = tuple(_put(a, ns) for a in arrays)
-    if check and not ent.checked:
-        assert_communication_free(ent.fn.lower(
-            _put(ws.sched[0], ns), _put(ws.valid[0], ns), *tables))
-        ent.checked = True
+    with obs.trace("wave/setup", phase="exec"):
+        arrays = plan.input_arrays()
+        key = ("wave", plan.signature(), mesh, ws.batch)
+        ent = _CACHE.get(key)
+        obs.event("compile_cache", kind="wave", hit=ent is not None)
+        if ent is None:
+            fn = _wave_fn(plan, mesh, len(arrays))
+            ent = _CACHE[key] = _Entry(fn, _sharding(mesh))
+        ns = ent.sharding
+        tables = tuple(_put(a, ns) for a in arrays)
+        if check and not ent.checked:
+            assert_communication_free(ent.fn.lower(
+                _put(ws.sched[0], ns), _put(ws.valid[0], ns), *tables))
+            ent.checked = True
     local = _local_rows(mesh)
-    traced = obs.is_enabled()
 
     def emit(rows, out) -> Wave:
         payload, valid = out
-        if traced:
-            # measurement mode: drain the async dispatch here so device
-            # time lands in its own span (costs overlap when disabled)
-            with obs.trace("wave/device", phase="exec"):
-                jax.block_until_ready((payload, valid))
         with obs.trace("wave/sink", phase="sink"):
             kept = tuple(r if local[d] else None for d, r in enumerate(rows))
             return Wave(payload=_consumable(payload),
@@ -614,7 +621,7 @@ def _slab_fn(slot_fn, mesh: Mesh, n_rows: int):
         return payload[None], (ok & valid[0][:, None])[None]
 
     return jax.jit(jax.shard_map(
-        step, mesh=mesh, in_specs=(spec,) * (1 + n_rows),
+        _named(step, "slab"), mesh=mesh, in_specs=(spec,) * (1 + n_rows),
         out_specs=(spec, spec), check_vma=False))
 
 
@@ -650,8 +657,6 @@ def run_slab(slot_fn_thunk: Callable, signature: tuple, valid: np.ndarray,
         inputs = (_put(valid, ns),) + tuple(_put(r, ns) for r in rows)
     with obs.trace("slab/exec", phase="exec", mode="slab"):
         payload, ok = ent.fn(*inputs)
-        if obs.is_enabled():
-            jax.block_until_ready((payload, ok))
     return _consumable(payload), _consumable(ok)
 
 

@@ -6,11 +6,8 @@ Two layers:
   :func:`achieved_fraction` turn the static FLOP/byte estimates of
   :class:`repro.launch.hlocost.HloCost` into a time floor
   ``max(flops/peak_flops, bytes/peak_bw)`` and compare it against
-  measured span time.  :func:`program_summary` does this for one
-  lowered program; :func:`trace_summary` joins a captured
-  :class:`repro.obs.Tracer` with a ``{name: lowered}`` program map, so
-  benchmark records carry "this run achieved X% of its roofline" next
-  to the phase breakdown.
+  a measured time.  :func:`program_summary` does this for one lowered
+  program.
 
 * the legacy table CLI — aggregate dry-run JSONs into the
   EXPERIMENTS.md roofline table:
@@ -89,7 +86,8 @@ def program_summary(lowered, measured_s: Optional[float] = None,
 
     ``lowered`` is a ``jax.stages.Lowered``/``Compiled`` (or an
     :class:`HloCost` already built from one).  ``measured_s`` is the
-    span-measured execution time to compare against the floor."""
+    program's device time (from a profiler trace; ``repro.obs`` spans
+    time host work only) to compare against the floor."""
     cost = lowered if isinstance(lowered, HloCost) else HloCost.from_lowered(lowered)
     peaks = peaks if peaks is not None else default_peaks()
     floor = roofline_seconds(cost.flops, cost.bytes, peaks)
@@ -104,27 +102,6 @@ def program_summary(lowered, measured_s: Optional[float] = None,
         "achieved_fraction": achieved_fraction(
             cost.flops, cost.bytes, measured_s or 0.0, peaks),
     }
-
-
-def trace_summary(tr, programs: Optional[Dict[str, object]] = None,
-                  peaks: Optional[Peaks] = None) -> dict:
-    """Join a captured :class:`repro.obs.Tracer` with lowered programs.
-
-    ``programs`` maps a span-name prefix (``"run"``, ``"wave"``,
-    ``"slab"``) to the lowered program whose executions those spans
-    timed; each entry gets a :func:`program_summary` with
-    ``measured_s`` summed from the matching exec-phase spans (falling
-    back to the trace's total exec time when no span matches)."""
-    totals = tr.phase_totals()
-    out = {"phases": totals, "programs": {}}
-    spans = [s for s in tr.spans() if not s.instant and s.phase == "exec"]
-    for name, lowered in (programs or {}).items():
-        measured = sum(s.seconds for s in spans
-                       if s.name == name or s.name.startswith(name + "/"))
-        if not measured:
-            measured = totals.get("exec_s", 0.0)
-        out["programs"][name] = program_summary(lowered, measured, peaks)
-    return out
 
 
 # --------------------------------------------------------------------------

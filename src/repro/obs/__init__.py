@@ -1,11 +1,13 @@
 """repro.obs — spans, metrics and phase-attributed tracing.
 
 Zero-overhead-when-disabled, host-side-only observability for the
-whole stack: plan emitters open ``plan/*`` spans, the runtime opens
-``wave``/``run``/``slab`` spans (with device time split out at
-``block_until_ready`` boundaries) and emits compile-cache events, and
-the serving tier keeps queue/slab/cache/latency metrics in a
-Prometheus-style registry.
+whole stack: plan emitters open ``plan/*`` spans, the runtime and the
+streaming front door open ``wave/*``/``stream/*``/``run``/``slab``
+spans around host work (no span waits for the device) and emit
+compile-cache events, and the serving tier keeps
+queue/slab/cache/latency metrics in a Prometheus-style registry.
+Every enabled span is also a ``jax.profiler.TraceAnnotation``, so under
+the JAX profiler the spans share the device trace's clock.
 
     from repro import obs
 
@@ -21,13 +23,13 @@ from .metrics import (Counter, Gauge, Histogram, Registry, parse_exposition,
                       DEFAULT_BUCKETS)
 from .tracer import (NULL_SPAN, PHASES, Span, SpanRecord, Tracer, capture,
                      disable, enable, event, export_chrome, is_enabled,
-                     jax_profiler_trace, phase_totals, trace, tracer)
+                     phase_totals, trace, tracer)
 
 __all__ = [
     # tracer
     "NULL_SPAN", "PHASES", "Span", "SpanRecord", "Tracer", "capture",
     "disable", "enable", "event", "export_chrome", "is_enabled",
-    "jax_profiler_trace", "phase_totals", "trace", "tracer",
+    "phase_totals", "trace", "tracer",
     # metrics
     "Counter", "Gauge", "Histogram", "Registry", "parse_exposition",
     "DEFAULT_BUCKETS",

@@ -17,20 +17,22 @@ Design constraints, in order:
   :func:`trace` then returns one shared no-op context manager — no
   span object, no event record, no clock read.  Instrumented hot paths
   stay within noise (< 2% on the streaming benchmarks).
-* **Host-side only.**  Spans never cross into jitted programs — no
-  host callbacks in lowered IR, so ``repro.analyze``'s contract scan
-  is unaffected by instrumentation.  Device time is attributed by
-  closing a span after ``jax.block_until_ready`` at the call site
-  (the runtime does this only while tracing is enabled).
+* **Host-side only, and never blocking.**  Spans never cross into
+  jitted programs — no host callbacks in lowered IR, so
+  ``repro.analyze``'s contract scan is unaffected by instrumentation —
+  and no span waits for the device: a span times the host work inside
+  it, and a traced run dispatches exactly as an untraced one.
+* **On the device trace's clock.**  Every enabled span also opens a
+  ``jax.profiler.TraceAnnotation`` of its own name, so while the JAX
+  profiler runs, the spans land in its trace's host plane on the same
+  timeline as the device's ops; device time is read there.
 * **Monotonic clocks, thread-safe, nestable.**  Spans use
   ``time.perf_counter_ns`` (never wall-clock-of-day), keep a
   per-thread stack for parent attribution, and append finished records
   under a lock.
 
 Export targets the Chrome trace-event JSON schema (``chrome://tracing``
-/ `Perfetto <https://ui.perfetto.dev>`_ both load it); an optional
-bridge mirrors spans into ``jax.profiler`` annotations so they appear
-inside TensorBoard device traces.
+/ `Perfetto <https://ui.perfetto.dev>`_ both load it).
 """
 from __future__ import annotations
 
@@ -46,7 +48,7 @@ import jax.profiler
 __all__ = [
     "Span", "SpanRecord", "Tracer", "trace", "event", "enable", "disable",
     "is_enabled", "tracer", "capture", "phase_totals", "export_chrome",
-    "jax_profiler_trace", "PHASES",
+    "PHASES",
 ]
 
 # the canonical phase names benchmark records report
@@ -96,7 +98,9 @@ NULL_SPAN = _NullSpan()
 class Span:
     """One live span; created by :meth:`Tracer.span` only while the
     tracer is enabled.  Context-manager protocol: the clock starts at
-    ``__enter__`` and the record is appended at ``__exit__``."""
+    ``__enter__`` and the record is appended at ``__exit__``; a
+    ``jax.profiler.TraceAnnotation`` of the same name is open in
+    between."""
     __slots__ = ("_tracer", "name", "attrs", "_t0", "_id", "_parent", "_jax")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
@@ -118,16 +122,14 @@ class Span:
         self._parent = stack[-1] if stack else 0
         self._id = tr._next_id()
         stack.append(self._id)
-        if tr.jax_annotations:
-            self._jax = jax.profiler.TraceAnnotation(self.name)
-            self._jax.__enter__()
+        self._jax = jax.profiler.TraceAnnotation(self.name)
+        self._jax.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         dur = time.perf_counter_ns() - self._t0
-        if self._jax is not None:
-            self._jax.__exit__(*exc)
+        self._jax.__exit__(*exc)
         tr = self._tracer
         stack = tr._stack()
         if stack and stack[-1] == self._id:
@@ -147,9 +149,8 @@ class Tracer:
     when tracing is off.
     """
 
-    def __init__(self, enabled: bool = False, jax_annotations: bool = False):
+    def __init__(self, enabled: bool = False):
         self.enabled = bool(enabled)
-        self.jax_annotations = bool(jax_annotations)
         self._records: List[SpanRecord] = []
         self._lock = threading.Lock()
         self._local = threading.local()
@@ -226,15 +227,6 @@ class Tracer:
                 totals[p] += r.seconds
         return {f"{p}_s": t for p, t in totals.items()}
 
-    def summary(self) -> Dict[str, Any]:
-        """Aggregate view: per-name counts/totals plus the phase fold."""
-        agg: Dict[str, Dict[str, float]] = {}
-        for r in self.spans():
-            a = agg.setdefault(r.name, {"count": 0, "total_s": 0.0})
-            a["count"] += 1
-            a["total_s"] += r.seconds
-        return {"phases": self.phase_totals(), "spans": agg}
-
     # ------------------------------------------------------------ export
 
     def export_chrome(self, path: Optional[str] = None) -> dict:
@@ -296,12 +288,10 @@ def is_enabled() -> bool:
     return _TRACER.enabled
 
 
-def enable(jax_annotations: bool = False, clear: bool = False) -> Tracer:
-    """Turn tracing on (optionally mirroring spans into
-    ``jax.profiler`` annotations); returns the tracer."""
+def enable(clear: bool = False) -> Tracer:
+    """Turn tracing on; returns the tracer."""
     if clear:
         _TRACER.clear()
-    _TRACER.jax_annotations = bool(jax_annotations)
     _TRACER.enabled = True
     return _TRACER
 
@@ -336,7 +326,7 @@ def export_chrome(path: Optional[str] = None) -> dict:
 
 
 @contextlib.contextmanager
-def capture(jax_annotations: bool = False) -> Iterator[Tracer]:
+def capture() -> Iterator[Tracer]:
     """Scoped tracing: install a *fresh* enabled tracer for the block,
     restore the previous one after.
 
@@ -346,17 +336,8 @@ def capture(jax_annotations: bool = False) -> Iterator[Tracer]:
     """
     global _TRACER
     prev = _TRACER
-    _TRACER = Tracer(enabled=True, jax_annotations=jax_annotations)
+    _TRACER = Tracer(enabled=True)
     try:
         yield _TRACER
     finally:
         _TRACER = prev
-
-
-@contextlib.contextmanager
-def jax_profiler_trace(logdir: str) -> Iterator[None]:
-    """Bridge to the JAX device profiler: wraps ``jax.profiler.trace``
-    so a traced region also produces a TensorBoard-loadable device
-    profile next to the host-side span trace."""
-    with jax.profiler.trace(logdir):
-        yield

@@ -1,14 +1,20 @@
-"""repro.obs: tracer semantics, metrics exposition, phase attribution,
-the lint-role carve-out, and the roofline join."""
+"""repro.obs: tracer semantics, its mirror into the JAX profiler's
+trace, spans that never wait for the device, the stream's host spans,
+metrics exposition, phase attribution, the lint-role carve-out, and the
+roofline model."""
 import json
 import os
 import threading
 
+import jax
+import numpy as np
 import pytest
 
 from repro import obs
 from repro.analyze.lint import RULE_WALLCLOCK, lint_paths, role_of
-from repro.api import BA, GNM, GNP, RMAT, SBM, generate
+from repro.api import BA, GNM, GNP, RHG, RMAT, SBM, generate, iter_edge_chunks
+from repro.core import rgg
+from repro.distrib import runtime
 from repro.launch import roofline
 from repro.launch.hlocost import HloCost
 
@@ -114,6 +120,139 @@ def test_capture_restores_previous_tracer():
     with obs.capture() as tr:
         assert obs.tracer() is tr and obs.is_enabled()
     assert obs.tracer() is before and not obs.is_enabled()
+
+
+class _AnnotationSpy:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records the names
+    opened and whether each was closed."""
+
+    def __init__(self):
+        self.opened, self.closed = [], 0
+
+    def __call__(self, name, **kw):
+        spy = self
+
+        class _Annotation:
+            def __enter__(self):
+                spy.opened.append(name)
+                return self
+
+            def __exit__(self, *exc):
+                spy.closed += 1
+                return False
+
+        return _Annotation()
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_span_mirrors_into_a_profiler_annotation(monkeypatch, enabled):
+    """An enabled span opens a ``TraceAnnotation`` of its own name (so
+    it lands in the JAX profiler's trace on the device's clock) and
+    closes it; a disabled one opens none."""
+    spy = _AnnotationSpy()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", spy)
+    obs.disable()
+    if enabled:
+        with obs.capture():
+            with obs.trace("wave/rows", phase="sink"):
+                with obs.trace("stream/chunk"):
+                    pass
+        assert spy.opened == ["wave/rows", "stream/chunk"]
+        assert spy.closed == 2
+    else:
+        with obs.trace("wave/rows", phase="sink"):
+            pass
+        assert spy.opened == [] and spy.closed == 0
+
+
+# ------------------------------------------- no span waits for the device
+
+def _gnm_plan():
+    return GNM(n=128, m=600, seed=2, chunks=8).plan(2)
+
+
+def _flat(payload, valid):
+    return np.asarray(payload)[np.asarray(valid)]
+
+
+def _stream(plan):
+    return [_flat(p, v) for _, _, p, v in runtime.stream_slots(plan, batch=2)]
+
+
+def _run(plan):
+    return [_flat(*runtime.run(plan, check=False)[:2])]
+
+
+def _slab(plan):
+    mesh = runtime.mesh_for(plan.num_pes)
+    ppd = plan.num_pes // runtime.mesh_size(mesh)
+    rows = tuple(a[::ppd, :2] for a in plan.input_arrays())
+    valid = np.ones(rows[0].shape[:2], bool)
+    return [_flat(*runtime.run_slab(plan.slot_fn, plan.signature(), valid,
+                                    rows, mesh, check=False))]
+
+
+@pytest.mark.parametrize("execute", [_stream, _run, _slab],
+                         ids=["stream_waves", "run", "run_slab"])
+def test_traced_execution_never_blocks(monkeypatch, execute):
+    """Under ``obs.capture()`` the runtime dispatches exactly as it does
+    untraced: no ``jax.block_until_ready`` anywhere, the same output."""
+    plan = _gnm_plan()
+    obs.disable()
+    want = execute(plan)
+    calls = []
+    real = jax.block_until_ready
+
+    def spy(x):
+        calls.append(x)
+        return real(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", spy)
+    with obs.capture() as tr:
+        got = execute(plan)
+    assert calls == []
+    assert tr.spans(), "tracing was on, so the runtime recorded spans"
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize("spec,batch", [
+    (GNM(n=256, m=1500, seed=3, chunks=8), 1),
+    (RHG(n=300, avg_deg=6, gamma=2.7, seed=4), 4),
+], ids=["gnm-chunk-batch1", "rhg-pair-batch4"])
+def test_stream_host_spans_count_chunks(spec, batch):
+    """``wave/rows`` (the row slice in ``Wave.chunks``) and
+    ``stream/chunk`` (building each ``EdgeChunk``) open once per chunk
+    yielded, ``wave/setup`` once per plan; none is left open across a
+    yield, so each closes before the consumer sees its chunk."""
+    with obs.capture() as tr:
+        n = 0
+        for _ in iter_edge_chunks(spec, 2, batch=batch):
+            n += 1
+            assert tr._stack() == []
+    names = [r.name for r in tr.spans()]
+    assert n > 1
+    assert names.count("wave/rows") == n
+    assert names.count("stream/chunk") == n
+    assert names.count("wave/setup") == 1
+
+
+def test_device_programs_are_named_by_step():
+    """The wave, run and slab steps lower to programs named for the step
+    (``jit_<name>`` in the device trace's ``XLA Modules`` line), not all
+    ``jit_step``."""
+    chunk = _gnm_plan()
+    pair = RHG(n=300, avg_deg=6, gamma=2.7, seed=4).plan(2)
+    point = rgg.rgg_point_plan(11, 300, 0.07, 2, 2, chunk_P=16)
+    for plan, name in ((chunk, "wave_chunk"), (pair, "wave_pair"),
+                       (point, "wave_point")):
+        assert f"@jit_{name}" in runtime.lower_wave(plan).as_text()
+    assert "@jit_run" in runtime.lower_run(chunk).as_text()
+    rows = tuple(a[:1, :2] for a in chunk.input_arrays())
+    low = runtime.lower_slab(chunk.slot_fn(), np.ones((1, 2), bool), rows,
+                             runtime.mesh_for(1))
+    assert "@jit_slab" in low.as_text()
 
 
 # ---------------------------------------------------------------- metrics
@@ -272,19 +411,6 @@ def test_program_summary_from_hlo_cost():
     s = roofline.program_summary(cost, measured_s=cost.flops / 1e9 * 2, peaks=peaks)
     assert s["bound"] == "compute"
     assert s["achieved_fraction"] == pytest.approx(0.5)
-
-
-def test_trace_summary_joins_spans_with_programs():
-    with obs.capture() as tr:
-        with obs.trace("run/exec", phase="exec"):
-            pass
-    out = roofline.trace_summary(
-        tr, programs={"run": HloCost(_TOY_HLO)},
-        peaks=roofline.Peaks(1e9, 1e12))
-    assert set(out["phases"]) == {"plan_s", "exec_s", "sink_s"}
-    prog = out["programs"]["run"]
-    assert prog["flops"] == 2 * 128 ** 3
-    assert prog["measured_s"] == pytest.approx(out["phases"]["exec_s"])
 
 
 def test_peaks_keyed_by_device_kind():
