@@ -107,7 +107,8 @@ def stream_gnm(n: int, m: int, P: int, mesh: Mesh, keep=()):
         rows.append((c.pe, c.count, s))
         if c.pe in kept:
             kept[c.pe].append(c.edges())
-    summary = np.asarray(jnp.stack([s for _, _, s in rows])) if rows else \
+    # each chunk's summary stays on its mesh row's device: stack on the host
+    summary = np.stack(jax.device_get([s for _, _, s in rows])) if rows else \
         np.zeros((0, 3), np.int64)
     seconds = time.perf_counter() - t0
     counts = np.array([k for _, k, _ in rows], np.int64)

@@ -540,6 +540,14 @@ def iter_edge_chunks(
     ``jax.make_mesh``; ``check`` asserts the zero-collective invariant
     on the lowered wave step itself (once per program signature).
 
+    Each chunk's buffer and mask are the wave program's own output
+    buffers on that mesh row's device: no eager op runs between the
+    wave step and the consumer.  An unbatched stream (``batch <= 1``)
+    drops the unit batch axis inside the program.  On a mesh of more
+    than one device a chunk therefore lives on its own mesh row's device
+    alone, not replicated: bring chunks of different rows to one device
+    (or the host, ``jax.device_get``) before combining them.
+
     ``overlap > 0`` streams through a lazily segmented plan
     (:func:`plan_emitter` with that many segments): plan emission runs
     on a background thread while earlier segments' waves execute, so
@@ -563,8 +571,6 @@ def iter_edge_chunks(
         with obs.trace("stream/chunk", phase="sink"):
             count = (int(chunk_counts[pe, slots].sum())
                      if chunk_counts is not None else None)
-            if batch <= 1:
-                payload, valid = payload[0], valid[0]
             chunk = EdgeChunk(buffer=payload, mask=valid, count=count,
                               pe=int(pe))
         yield chunk
@@ -590,7 +596,9 @@ def iter_points(
     follow the exact hashed per-cell streams the family's edge plan
     recomputes, in gid order within each PE: grouping by ``pe`` and
     concatenating ``chunk.points()`` reproduces the masked
-    ``engine.run_points`` output of ``spec.point_plan(P)``.
+    ``engine.run_points`` output of ``spec.point_plan(P)``.  Chunks are
+    the wave program's own buffers, placed as in
+    :func:`iter_edge_chunks`.
     """
     point_plan = getattr(spec, "point_plan", None)
     if point_plan is None:
@@ -600,10 +608,7 @@ def iter_points(
     plan = point_plan(P, rng_impl=rng_impl)
     for pe, slots, payload, valid in runtime.stream_slots(
             plan, mesh=mesh, batch=batch, prefetch=prefetch, check=check):
-        if batch <= 1:
-            yield PointChunk(buffer=payload[0], mask=valid[0], pe=int(pe))
-        else:
-            yield PointChunk(buffer=payload, mask=valid, pe=int(pe))
+        yield PointChunk(buffer=payload, mask=valid, pe=int(pe))
 
 
 def serve(specs, P: int = 1, **kwargs):

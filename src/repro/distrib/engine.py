@@ -515,7 +515,7 @@ def stream_chunk_edges(plan: ChunkPlan, check: bool = False, with_pe: bool = Fal
 
     for pe, slots, payload, _ in runtime.stream_slots(
             plan, mesh=mesh, batch=1, prefetch=prefetch, check=check):
-        out = (payload[0], int(plan.count[pe, slots[0]]))
+        out = (payload, int(plan.count[pe, slots[0]]))
         yield (int(pe), *out) if with_pe else out
 
 
@@ -671,8 +671,7 @@ def stream_points(plan: PointPlan, check: bool = False, batch: int = 1,
 
     for pe, slots, payload, mask in runtime.stream_slots(
             plan, mesh=mesh, batch=batch, prefetch=prefetch, check=check):
-        out = (payload[0], mask[0]) if batch <= 1 else (payload, mask)
-        yield (int(pe), *out) if with_pe else out
+        yield (int(pe), payload, mask) if with_pe else (payload, mask)
 
 
 # --------------------------------------------------------------------------
@@ -1152,5 +1151,4 @@ def stream_pair_edges(plan: PairPlan, check: bool = False, batch: int = 1,
 
     for pe, slots, payload, keep in runtime.stream_slots(
             plan, mesh=mesh, batch=batch, prefetch=prefetch, check=check):
-        out = (payload[0], keep[0]) if batch <= 1 else (payload, keep)
-        yield (int(pe), *out) if with_pe else out
+        yield (int(pe), payload, keep) if with_pe else (payload, keep)

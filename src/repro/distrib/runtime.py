@@ -452,11 +452,15 @@ def wave_schedule(plan: PlanProgram, D: int, batch: int = 1) -> WaveSchedule:
     return WaveSchedule(sched, valid, tuple(tuple(r) for r in rows), B)
 
 
-def _wave_fn(plan: PlanProgram, mesh: Mesh, n_tables: int):
+def _wave_fn(plan: PlanProgram, mesh: Mesh, n_tables: int, squeeze: bool):
     """The jitted shard_map'd wave step: gather each mesh row's next
     ``[B]`` slots from its local table shard, run the slot fn, and mask
-    padding rows out of the validity output.  Named after the plan's
-    kind (``wave_chunk``, ``wave_pair``, ``wave_point``)."""
+    padding rows out of the validity output.  Each device's output
+    block is its mesh row itself (``[B, ...]``, or ``[...]`` with the
+    unit batch axis dropped when ``squeeze``: an unbatched stream), so
+    the global outputs are ``[D*B, ...]`` sharded on their leading axis
+    and a row is one device's buffer.  Named after the plan's kind (``wave_chunk``,
+    ``wave_pair``, ``wave_point``)."""
     spec = PartitionSpec(mesh.axis_names)
     one = plan.slot_fn()
 
@@ -465,7 +469,10 @@ def _wave_fn(plan: PlanProgram, mesh: Mesh, n_tables: int):
         s, v = sched[0], valid[0]
         rows = [t[s[:, 0], s[:, 1]] for t in tables]      # local gather [B, ...]
         payload, ok = jax.vmap(one)(*rows)
-        return payload[None], (ok & v[:, None])[None]
+        ok = ok & v[:, None]
+        if squeeze:                                       # B == 1: a bitcast
+            return payload[0], ok[0]
+        return payload, ok
 
     kind = type(plan).__name__.lower().removesuffix("plan")
     return jax.jit(jax.shard_map(
@@ -474,34 +481,57 @@ def _wave_fn(plan: PlanProgram, mesh: Mesh, n_tables: int):
         check_vma=False))
 
 
+def _row_blocks(arr, D: int):
+    """``(blocks, view)``: mesh row ``d``'s block of a wave output at
+    ``blocks[d]``, and whether the blocks are the wave program's own
+    device buffers.  On a single-process mesh each row is a device's
+    addressable shard (zero-copy, nothing dispatched); a multi-process
+    output is already on the host, so its rows are NumPy views of it."""
+    n = arr.shape[0] // D
+    if isinstance(arr, np.ndarray):
+        return [arr[d * n: (d + 1) * n] for d in range(D)], False
+    blocks = [None] * D
+    for sh in arr.addressable_shards:
+        blocks[(sh.index[0].start or 0) // n] = sh.data
+    return blocks, True
+
+
 @dataclass(frozen=True)
 class Wave:
     """One executed ``[D, batch]`` slab: every mesh row's next slots.
 
-    ``payload[d]`` / ``valid[d]`` are mesh row ``d``'s batch of slot
-    outputs with the padding already masked; ``rows[d]`` names the
-    owning virtual PE and its slot ids (``None`` for an all-padding or
-    non-addressable row).  On a single-process mesh the slabs are
-    still *device* arrays — the host only blocks when a consumer
-    materializes one.  Iterating :meth:`chunks` yields the per-PE view
-    in pe order within the wave."""
-    payload: object         # [D, B, ...] device array (host if multi-process)
-    valid: object           # [D, B, L]
+    ``payload`` / ``valid`` are the wave program's outputs, ``[D*B,
+    ...]`` sharded on the leading axis (``[D*cap, ...]`` for an
+    unbatched stream), padding already masked: each device holds
+    its mesh row's block.  ``rows[d]`` names the owning virtual PE and
+    its slot ids (``None`` for an all-padding or non-addressable row).
+    On a single-process mesh the outputs stay on the device — the host
+    only blocks when a consumer materializes one.  Iterating
+    :meth:`chunks` yields the per-PE view in pe order within the
+    wave."""
+    payload: object         # [D*B, ...] device array (host if multi-process)
+    valid: object           # [D*B, L]
     rows: tuple             # [D] -> (pe, slots) | None
 
     def chunks(self) -> Iterator[Tuple[int, np.ndarray, object, object]]:
         """Yield ``(pe, slots, payload [B, ...], valid [B, L])`` per
-        non-empty mesh row.  Rows keep the full static batch shape —
-        ragged tails beyond ``len(slots)`` are masked, never trimmed,
-        so jitted downstream consumers see one shape per program and
-        never retrace."""
+        non-empty mesh row (``[cap, ...]`` / ``[cap]`` when unbatched).
+        Each row is the wave program's own buffer on that row's device,
+        handed out without an eager op.  Rows keep the full static
+        batch shape — ragged tails beyond ``len(slots)`` are masked,
+        never trimmed, so jitted downstream consumers see one shape per
+        program and never retrace."""
+        D = len(self.rows)
+        payload = valid = None
         for d, row in enumerate(self.rows):
             if row is None:
                 continue
-            pe, slots = row
             with obs.trace("wave/rows", phase="sink"):
-                payload, valid = self.payload[d], self.valid[d]
-            yield pe, slots, payload, valid
+                if payload is None:
+                    payload, view = _row_blocks(self.payload, D)
+                    valid, _ = _row_blocks(self.valid, D)
+                obs.event("wave/row", view=view)
+            yield row[0], row[1], payload[d], valid[d]
 
 
 def lower_wave(plan: PlanProgram, mesh: Optional[Mesh] = None,
@@ -512,15 +542,16 @@ def lower_wave(plan: PlanProgram, mesh: Optional[Mesh] = None,
     :mod:`repro.analyze` scans this module for every registered plan,
     so the zero-collective / no-host-callback / deterministic-PRNG
     contracts are verified on the program :func:`stream_waves` actually
-    dispatches, not a per-slot proxy.  Returns ``None`` for a plan with
-    no owned slots (nothing would ever execute)."""
+    dispatches at that ``batch``, not a per-slot proxy.  Returns
+    ``None`` for a plan with no owned slots (nothing would ever
+    execute)."""
     mesh = _resolve_mesh(plan, mesh)
     D = mesh_size(mesh)
     ws = wave_schedule(plan, D, batch)
     if not ws.num_waves:
         return None
     arrays = plan.input_arrays()
-    fn = _wave_fn(plan, mesh, len(arrays))
+    fn = _wave_fn(plan, mesh, len(arrays), batch <= 1)
     ns = _sharding(mesh)
     tables = tuple(_put(a, ns) for a in arrays)
     return fn.lower(_put(ws.sched[0], ns), _put(ws.valid[0], ns), *tables)
@@ -544,6 +575,13 @@ def stream_waves(
     the lowered wave step itself — the shard_map'd program that actually
     runs, not a single slot's fn — once per program signature.
 
+    Each mesh row's output is the wave program's own per-device buffer
+    (:class:`Wave`), so handing a row to the consumer runs no eager op.
+    An unbatched stream (``batch <= 1``) drops the unit batch axis
+    inside the program, so its rows come out as ``[cap, ...]``, not
+    ``[1, cap, ...]``.  On a mesh of more than one device each row stays
+    on its own device.
+
     Per-PE stream order is exact: concatenating a PE's rows across
     waves reproduces its :func:`run` output prefix bit-for-bit, and on
     a single-row mesh the flattened wave order *is* pe-major run order.
@@ -565,11 +603,12 @@ def stream_waves(
         return
     with obs.trace("wave/setup", phase="exec"):
         arrays = plan.input_arrays()
-        key = ("wave", plan.signature(), mesh, ws.batch)
+        squeeze = batch <= 1
+        key = ("wave", plan.signature(), mesh, ws.batch, squeeze)
         ent = _CACHE.get(key)
         obs.event("compile_cache", kind="wave", hit=ent is not None)
         if ent is None:
-            fn = _wave_fn(plan, mesh, len(arrays))
+            fn = _wave_fn(plan, mesh, len(arrays), squeeze)
             ent = _CACHE[key] = _Entry(fn, _sharding(mesh))
         ns = ent.sharding
         tables = tuple(_put(a, ns) for a in arrays)
@@ -682,9 +721,10 @@ def stream_slots(
 ) -> Iterator[Tuple[int, np.ndarray, np.ndarray, np.ndarray]]:
     """Flattened :func:`stream_waves`: yield ``(pe, slots, payload,
     valid)`` per mesh-row batch, in wave order (pe-major on a
-    single-row mesh).  The per-(pe, slot) consumer loop the legacy
-    ``stream_*`` facades are built on.  Accepts a :class:`PlanEmitter`
-    for the overlapped path (``pe`` is then the global PE id)."""
+    single-row mesh).  The per-(pe, slot) consumer loop the front door
+    and the legacy ``stream_*`` facades are built on.  Accepts a
+    :class:`PlanEmitter` for the overlapped path (``pe`` is then the
+    global PE id); rows are shaped as in :func:`stream_waves`."""
     for wave in stream_waves(plan, mesh=mesh, batch=batch,
                              prefetch=prefetch, check=check):
         yield from wave.chunks()

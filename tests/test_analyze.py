@@ -72,6 +72,33 @@ class TestPass1:
         rep = hloscan.scan_lowered(low)
         assert rep.ok
 
+    @pytest.mark.parametrize("batch", [2, 1], ids=["batched", "unbatched"])
+    def test_both_wave_forms_communication_free(self, chunk_plan, monkeypatch,
+                                                batch):
+        """The batched wave step and the unbatched one (batch axis
+        dropped in the program) both pass the zero-collective scanner,
+        through ``lower_wave`` and through ``check=True`` on the form
+        ``stream_waves`` dispatches."""
+        low = runtime.lower_wave(chunk_plan, batch=batch)
+        hloscan.assert_communication_free(low)
+        cap = chunk_plan.capacity
+        B = runtime.wave_schedule(chunk_plan, 1, batch).batch
+        want = (cap, 2) if batch == 1 else (B, cap, 2)
+        assert low.out_info[0].shape == want
+        runtime.cache_clear()
+        checked = []
+        real = runtime.assert_communication_free
+
+        def spy(lowered):
+            checked.append(lowered.as_text())
+            return real(lowered)
+
+        monkeypatch.setattr(runtime, "assert_communication_free", spy)
+        waves = list(runtime.stream_waves(chunk_plan, batch=batch,
+                                          check=True))
+        assert checked == [low.as_text()]
+        assert waves[0].payload.shape == want
+
     def test_planted_psum_is_exactly_one_collective_finding(self, chunk_plan):
         low = runtime.lower_run(_PlantedCollective(chunk_plan))
         rep = hloscan.scan_lowered(low)

@@ -79,9 +79,11 @@ def test_certificate_predicate_compiles(one_chip):
 
 def test_chunk_wave_step_compiles(topo):
     """One wave of a directed G(n, m) stream over 1024 PEs on a one-chip
-    mesh, collective-free.  m is cut to 2^20 (chunks of ~2^10 edges):
-    the compile time grows with the chunk capacity, to about a minute
-    for the README's 2^30-edge stream."""
+    mesh, collective-free, in both forms: batched, and unbatched (the
+    batch axis dropped in the program, as a batch-1 stream dispatches
+    it).  m is cut to 2^20 (chunks of ~2^10 edges): the compile time
+    grows with the chunk capacity, to about a minute for the README's
+    2^30-edge stream."""
     from repro.analyze.hloscan import assert_communication_free
     from repro.api import GNM
     from repro.distrib import runtime
@@ -90,8 +92,11 @@ def test_chunk_wave_step_compiles(topo):
     mesh = Mesh(np.array(topo.devices[:1]), ("pe",))
     ns = NamedSharding(mesh, PartitionSpec("pe"))
     tables = plan.input_arrays()
-    fn = runtime._wave_fn(plan, mesh, len(tables))
-    compiled = fn.lower(
-        _shape((1, 1, 2), jnp.int32, ns), _shape((1, 1), jnp.bool_, ns),
-        *(_shape(t.shape, t.dtype, ns) for t in tables)).compile()
-    assert_communication_free(compiled)
+    for squeeze in (False, True):
+        fn = runtime._wave_fn(plan, mesh, len(tables), squeeze)
+        compiled = fn.lower(
+            _shape((1, 1, 2), jnp.int32, ns), _shape((1, 1), jnp.bool_, ns),
+            *(_shape(t.shape, t.dtype, ns) for t in tables)).compile()
+        assert_communication_free(compiled)
+        rows = (plan.capacity, 2) if squeeze else (1, plan.capacity, 2)
+        assert compiled.out_info[0].shape == rows

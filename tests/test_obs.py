@@ -222,7 +222,7 @@ def test_traced_execution_never_blocks(monkeypatch, execute):
     (RHG(n=300, avg_deg=6, gamma=2.7, seed=4), 4),
 ], ids=["gnm-chunk-batch1", "rhg-pair-batch4"])
 def test_stream_host_spans_count_chunks(spec, batch):
-    """``wave/rows`` (the row slice in ``Wave.chunks``) and
+    """``wave/rows`` (a row handed out by ``Wave.chunks``) and
     ``stream/chunk`` (building each ``EdgeChunk``) open once per chunk
     yielded, ``wave/setup`` once per plan; none is left open across a
     yield, so each closes before the consumer sees its chunk."""
@@ -236,6 +236,20 @@ def test_stream_host_spans_count_chunks(spec, batch):
     assert names.count("wave/rows") == n
     assert names.count("stream/chunk") == n
     assert names.count("wave/setup") == 1
+
+
+@pytest.mark.parametrize("spec,batch", [
+    (GNM(n=256, m=1500, seed=3, chunks=8), 1),
+    (RHG(n=300, avg_deg=6, gamma=2.7, seed=4), 4),
+], ids=["gnm-chunk-batch1", "rhg-pair-batch4"])
+def test_wave_row_event_marks_each_row_a_view(spec, batch):
+    """``wave/row`` fires once per yielded row, and on a single-process
+    mesh every row is the wave program's own buffer (``view=True``)."""
+    with obs.capture() as tr:
+        n = sum(1 for _ in iter_edge_chunks(spec, 2, batch=batch))
+    rows = [r for r in tr.spans() if r.name == "wave/row"]
+    assert n > 1 and len(rows) == n
+    assert all(r.instant and r.attrs["view"] is True for r in rows)
 
 
 def test_device_programs_are_named_by_step():

@@ -3,11 +3,13 @@ stream == run bit-identity and P-invariance for all three plan types,
 ragged final waves padded (never retraced), the zero-collective check
 on the actual wave dispatch (once per program signature), and
 whole-mesh wave execution on 8 devices."""
+import contextlib
 import os
 import subprocess
 import sys
 import textwrap
 
+import jax
 import numpy as np
 import pytest
 
@@ -34,9 +36,17 @@ def _plan_of(kind: str, P: int):
 def _reassemble(plan, **stream_kw) -> np.ndarray:
     """Group streamed rows by PE and concatenate the valid payload —
     the documented reconstruction of the run output from wave prefixes
-    (per-PE stream order is exact; PEs concatenate pe-major)."""
+    (per-PE stream order is exact; PEs concatenate pe-major).  Every
+    row is the wave program's own buffer: one device's array, ``[B,
+    ...]`` batched, or ``[...]`` with the unit batch axis dropped."""
+    unbatched = stream_kw.get("batch", 1) <= 1
     per_pe = {}
     for pe, _, payload, valid in runtime.stream_slots(plan, **stream_kw):
+        assert isinstance(payload, jax.Array) and isinstance(valid, jax.Array)
+        assert len(payload.devices()) == 1
+        assert valid.devices() == payload.devices()
+        assert valid.ndim == (1 if unbatched else 2)
+        assert payload.shape[:valid.ndim] == valid.shape
         per_pe.setdefault(pe, []).append(np.asarray(payload)[np.asarray(valid)])
     if not per_pe:
         return np.zeros((0,))
@@ -48,6 +58,28 @@ def _run_flat(plan) -> np.ndarray:
     return np.asarray(payload)[np.asarray(valid)]
 
 
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+@contextlib.contextmanager
+def _no_compiles():
+    """Fail if any program compiles inside the block (a ``jax.monitoring``
+    listener): with the wave programs built, handing rows to the
+    consumer must run no eager slice or getitem."""
+    compiled = []
+
+    def listen(event, duration, fun_name=None, **_):
+        if event == COMPILE_EVENT:
+            compiled.append(fun_name)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        yield
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiled == [], compiled
+
+
 # ------------------------------------------- stream == run bit-identity
 
 @pytest.mark.parametrize("kind", ["chunk", "point", "pair"])
@@ -56,11 +88,22 @@ def _run_flat(plan) -> np.ndarray:
 def test_stream_equals_run_bit_identical(kind, batch, prefetch):
     """Concatenating wave prefixes (grouped by PE) reproduces the
     materializing run output exactly, for every plan type, batch and
-    prefetch depth."""
+    prefetch depth, plain and through the overlapped plan emitter.  The
+    rows are the wave programs' own buffers: once the programs are built
+    (streaming waves without taking rows), handing the rows out compiles
+    nothing."""
     plan = _plan_of(kind, 4)
-    streamed = _reassemble(plan, batch=batch, prefetch=prefetch)
-    np.testing.assert_array_equal(streamed, _run_flat(plan))
-    assert len(streamed) > 0
+    want = _run_flat(plan)
+    assert len(want) > 0
+    jax.clear_caches()     # an eager op another test compiled would hide
+    for source in (lambda: plan,
+                   lambda: runtime.PlanEmitter.from_plan(plan, 2)):
+        for _ in runtime.stream_waves(source(), batch=batch,
+                                      prefetch=prefetch):
+            pass
+        with _no_compiles():
+            streamed = _reassemble(source(), batch=batch, prefetch=prefetch)
+        np.testing.assert_array_equal(streamed, want)
 
 
 def _row_sorted(a: np.ndarray) -> np.ndarray:
@@ -177,30 +220,58 @@ def test_engine_stream_facades_check_lowers_wave_step(monkeypatch):
 # ----------------------------------------------------- point streaming
 
 def test_stream_points_matches_run_points():
-    """The PointPlan streaming path (new in this PR): masked streamed
-    positions reassemble to run_points' masked output exactly."""
+    """The PointPlan streaming path: masked streamed positions
+    reassemble to run_points' masked output exactly, unbatched
+    (``[cap, dim]`` rows) and batched."""
     plan = _plan_of("point", 4)
     pts, mask, hlo = engine.run_points(plan, check=True)
     assert not engine.collective_ops_in(hlo)
+    for batch in (1, 2):
+        per_pe = {}
+        for pe, buf, m in engine.stream_points(plan, batch=batch,
+                                               with_pe=True):
+            assert buf.shape == ((pts.shape[2:]) if batch == 1
+                                 else (2, *pts.shape[2:]))
+            per_pe.setdefault(pe, []).append(np.asarray(buf)[np.asarray(m)])
+        streamed = np.concatenate(
+            [x for pe in sorted(per_pe) for x in per_pe[pe]])
+        np.testing.assert_array_equal(streamed, pts[mask])
+        assert len(streamed) == RGG_SPEC.n
+
+
+@pytest.mark.parametrize("kind", ["chunk", "pair"])
+def test_engine_stream_facades_match_run(kind):
+    """The legacy unbatched facades hand out ``[cap, 2]`` buffers whose
+    valid edges (the ``count`` prefix of a chunk, the ``keep`` mask of
+    a pair) reassemble to the run output."""
+    plan = _plan_of(kind, 4)
+    payload, _, _ = runtime.run(plan, check=False)
     per_pe = {}
-    for pe, buf, m in engine.stream_points(plan, batch=2, with_pe=True):
-        per_pe.setdefault(pe, []).append(np.asarray(buf)[np.asarray(m)])
+    if kind == "chunk":
+        for pe, buf, count in engine.stream_chunk_edges(plan, with_pe=True):
+            assert buf.shape == payload.shape[2:]
+            per_pe.setdefault(pe, []).append(np.asarray(buf)[:count])
+    else:
+        for pe, buf, keep in engine.stream_pair_edges(plan, with_pe=True):
+            assert buf.shape == payload.shape[2:]
+            per_pe.setdefault(pe, []).append(np.asarray(buf)[np.asarray(keep)])
     streamed = np.concatenate([x for pe in sorted(per_pe) for x in per_pe[pe]])
-    np.testing.assert_array_equal(streamed, pts[mask])
-    assert len(streamed) == RGG_SPEC.n
+    np.testing.assert_array_equal(streamed, _run_flat(plan))
 
 
 def test_iter_points_streams_graph_positions():
     """api.iter_points: the O(capacity) route to Graph.points — the
     streamed positions are exactly the materialized ones (as sets; gid
-    order is recovered per PE, positions are what matter here)."""
+    order is recovered per PE, positions are what matter here), at
+    batch 1 (the default) and batch 2."""
     g = generate(RGG_SPEC, 4, return_points=True)
-    streamed = np.concatenate(
-        [c.points() for c in iter_points(RGG_SPEC, 4, batch=2)])
-    assert streamed.shape == g.points.shape
-    a = {tuple(np.round(p, 12)) for p in streamed}
     b = {tuple(np.round(p, 12)) for p in g.points}
-    assert a == b
+    for batch in (1, 2):
+        chunks = list(iter_points(RGG_SPEC, 4, batch=batch))
+        assert all(c.mask.ndim == (1 if batch == 1 else 2) for c in chunks)
+        streamed = np.concatenate([c.points() for c in chunks])
+        assert streamed.shape == g.points.shape
+        assert {tuple(np.round(p, 12)) for p in streamed} == b
 
 
 def test_iter_points_rejects_non_geometric_specs():
@@ -235,7 +306,8 @@ class _FakePlan:
 def _run_with_devices(snippet: str, ndev: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
-    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["PYTHONPATH"] = os.pathsep.join(
+        os.path.join(REPO, d) for d in ("src", "tests"))
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(snippet)],
         env=env, capture_output=True, text=True, timeout=600,
@@ -247,28 +319,50 @@ def _run_with_devices(snippet: str, ndev: int = 8) -> str:
 def test_wave_streaming_uses_whole_mesh_and_matches_generate():
     """On a real 8-device mesh, every wave slab spans all 8 mesh rows
     (streaming uses the whole mesh, not the default device) and the
-    per-PE reassembly reproduces generate() bit-for-bit for both a
-    ChunkPlan and a PairPlan family."""
+    per-PE reassembly reproduces generate() bit-for-bit for a ChunkPlan
+    and two PairPlan families, at batch 1 and 2, plain and overlapped.
+    Each chunk is its row's own buffer, on that mesh row's device
+    alone, and handing chunks out compiles nothing once the wave
+    programs are built."""
     out = _run_with_devices("""
         import numpy as np, jax
-        from repro.api import GNM, RGG, generate, iter_edge_chunks
+        from repro.api import (GNM, RGG, RHG, generate, iter_edge_chunks,
+                               plan_emitter)
         from repro.distrib import runtime
+        from test_runtime import _no_compiles
 
         assert len(jax.devices()) == 8
         for spec in (GNM(n=1024, m=8000, seed=5, chunks=16),
-                     RGG(n=1024, radius=0.05, seed=3)):
+                     RGG(n=1024, radius=0.05, seed=3),
+                     RHG(n=1024, avg_deg=8, gamma=2.8, seed=3)):
             P = 8
             plan = spec.plan(P)
             waves = list(runtime.stream_waves(plan, batch=2))
-            D = waves[0].payload.shape[0]
+            D = len(waves[0].rows)
             assert D == 8, D  # one slab row per mesh device
+            # each device's output block is its row: [D*B, ...] over 8
+            assert len(waves[0].payload.sharding.device_set) == 8
+            assert waves[0].payload.shape[0] == D * 2
+            devices = runtime.mesh_for(P).devices.ravel()
             g = generate(spec, P)
-            per_pe = {}
-            for c in iter_edge_chunks(spec, P, batch=2):
-                per_pe.setdefault(c.pe, []).append(c.edges())
-            streamed = np.concatenate(
-                [e for pe in sorted(per_pe) for e in per_pe[pe]])
-            np.testing.assert_array_equal(streamed, g.edges)
+            for batch in (1, 2):
+                for overlap in (0, 4):
+                    source = (plan_emitter(spec, P, segments=overlap)
+                              if overlap else plan)
+                    for _ in runtime.stream_waves(source, batch=batch):
+                        pass
+                    with _no_compiles():
+                        chunks = list(iter_edge_chunks(
+                            spec, P, batch=batch, overlap=overlap))
+                    per_pe = {}
+                    for c in chunks:
+                        row = {devices[c.pe * D // P]}
+                        assert c.buffer.devices() == c.mask.devices() == row
+                        assert c.mask.ndim == (1 if batch == 1 else 2)
+                        per_pe.setdefault(c.pe, []).append(c.edges())
+                    streamed = np.concatenate(
+                        [e for pe in sorted(per_pe) for e in per_pe[pe]])
+                    np.testing.assert_array_equal(streamed, g.edges)
         print("WAVE8OK")
     """)
     assert "WAVE8OK" in out
