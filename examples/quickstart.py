@@ -35,7 +35,7 @@ def main():
         ("RHG (gamma=2.6)", RHG(n=1500, avg_deg=8, gamma=2.6, seed=seed)),
         ("RDG 2d (torus)", RDG(n=2000, seed=seed)),
         ("BA (d=4)", BA(n=n, d=4, seed=seed)),
-        ("R-MAT", RMAT(log_n=13, m=8 * n, seed=seed)),
+        ("R-MAT", RMAT(n=1 << 13, m=8 * n, seed=seed)),
     ]
     for name, spec in specs:
         stats(name, generate(spec, P))

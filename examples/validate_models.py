@@ -17,7 +17,7 @@ def main():
         GNM(n=n, m=8 * n, seed=2),
         SBM(n=n, blocks=8, p_in=0.02, p_out=0.001, seed=3),
         BA(n=n, d=8, seed=4),
-        RMAT(log_n=13, m=8 * n, seed=5),
+        RMAT(n=n, m=8 * n, seed=5),
         RHG(n=n, avg_deg=8, gamma=2.7, seed=6),
     ]
     for spec in specs:

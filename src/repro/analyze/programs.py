@@ -54,7 +54,7 @@ def small_specs() -> Dict[str, object]:
         "gnm": GNM(n=n, m=2 * n, seed=7, chunks=8),
         "gnp": GNP(n=n, p=0.05, seed=7, chunks=8),
         "ba": BA(n=n, d=2, seed=7),
-        "rmat": RMAT(log_n=6, m=2 * n, seed=7),
+        "rmat": RMAT(n=n, m=2 * n, seed=7, chunks=8),
         "sbm": SBM(n=n, blocks=2, p_in=0.2, p_out=0.02, seed=7),
         "rgg": RGG(n=n, radius=0.25, seed=7, chunks=8),
         "rhg": RHG(n=n, avg_deg=4.0, gamma=2.7, seed=7),

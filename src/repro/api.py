@@ -303,20 +303,35 @@ class BA:
 
 @dataclass(frozen=True)
 class RMAT:
-    """R-MAT with 2^log_n vertices and m edges (Graph 500 semantics:
-    self-loops and duplicates kept; paper §3.5.2)."""
-    log_n: int
+    """Graph 500's Kronecker generator: R-MAT with ``n`` vertices (a
+    power of two) and m edge tuples, self-loops and duplicates kept,
+    labels randomly permuted (paper §3.5.2; :mod:`repro.core.rmat`).
+
+    ``chunks`` sizes the virtual chunk grid of consecutive edge ids;
+    every grid and every P give the same edges."""
+    n: int
     m: int
     probs: Tuple[float, float, float, float] = (0.57, 0.19, 0.19, 0.05)
     seed: int = 0
     directed: bool = True
+    chunks: Optional[int] = None
+
+    def __post_init__(self):
+        if self.n < 2 or self.n & (self.n - 1):
+            raise ValueError(f"RMAT needs n a power of two >= 2, got {self.n}")
 
     @property
     def num_vertices(self) -> int:
-        return 1 << self.log_n
+        return self.n
+
+    @property
+    def log_n(self) -> int:
+        return self.n.bit_length() - 1
 
     def plan(self, P: int, *, rng_impl: str = DEFAULT_RNG):
-        return _rmat.rmat_plan(self.seed, self.log_n, self.m, P, self.probs, rng_impl)
+        k = _virtual_chunks(self.chunks, P)
+        return engine.deal_plan(_rmat.rmat_plan(
+            self.seed, self.log_n, self.m, k, self.probs, rng_impl), P)
 
 
 @dataclass(frozen=True)

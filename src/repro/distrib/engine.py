@@ -46,6 +46,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from ..core.prng import counter_uniform, fold_in64
+from ..core.rmat import edges as rmat_edges
 from ..core.sampling import (
     decode_directed,
     decode_rect,
@@ -367,7 +368,8 @@ def _edge_chunk_fn(capacity: int, rng_impl: str,
 
     Sampled kinds (DIRECTED/TRI/RECT) share one without-replacement
     index draw + per-kind decode; RMAT runs the per-edge hashed quadrant
-    descent (one fold_in per edge id, ``log_n`` uniforms); BA resolves
+    descent (one fold_in per edge id, ``log_n`` uniforms) and Graph 500's
+    vertex permutation (:func:`repro.core.rmat.edges`); BA resolves
     the Batagelj-Brandes position chain with a hashed ``while_loop``
     (Sanders-Schulz).  Only the branches for kinds actually present are
     lowered, so an RMAT plan never pays for the sampler's sort and vice
@@ -400,22 +402,8 @@ def _edge_chunk_fn(capacity: int, rng_impl: str,
                 v = jnp.where(kind == KIND_RECT, rv, v)
 
         if KIND_RMAT in kinds:
-            a, b, c = fparams[0], fparams[1], fparams[2]
-
-            def one_edge(eid):
-                k = fold_in64(key, eid)  # 64-bit safe: ids exceed 2^32 at scale
-                uu = jax.random.uniform(k, (log_n,), dtype=jnp.float64)
-                quad = (
-                    (uu >= a).astype(jnp.int64)
-                    + (uu >= a + b).astype(jnp.int64)
-                    + (uu >= a + b + c).astype(jnp.int64)
-                )
-                bits = jnp.arange(log_n - 1, -1, -1, dtype=jnp.int64)
-                src = jnp.sum((quad >= 2).astype(jnp.int64) << bits)
-                dst = jnp.sum((quad % 2) << bits)
-                return src, dst
-
-            ru, rv = jax.vmap(one_edge)(p1 + idx)
+            ru, rv = rmat_edges(key, p1 + idx, fparams[0], fparams[1],
+                                fparams[2], log_n)
             u = jnp.where(kind == KIND_RMAT, ru, u)
             v = jnp.where(kind == KIND_RMAT, rv, v)
 

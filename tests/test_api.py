@@ -33,7 +33,7 @@ ALL_SPECS = [
     GNP(n=200, p=0.03, seed=5),
     GNP(n=200, p=0.02, directed=True, seed=5),
     BA(n=128, d=2, seed=5),
-    RMAT(log_n=9, m=2000, seed=1),
+    RMAT(n=1 << 9, m=2000, seed=1),
     SBM(n=300, blocks=6, p_in=0.2, p_out=0.01, seed=5),
     RGG(n=300, radius=0.07, seed=11),
     RHG(n=400, avg_deg=8, gamma=2.8, seed=23),

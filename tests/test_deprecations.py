@@ -18,7 +18,7 @@ def _es(e):
     (er.gnm_undirected, (5, 100, 300), GNM(n=100, m=300, seed=5, chunks=2), 2),
     (er.gnp_undirected, (7, 100, 0.05), GNP(n=100, p=0.05, seed=7, chunks=2), 2),
     (ba.ba_union, (9, 100, 3), BA(n=100, d=3, seed=9), 2),
-    (rmat.rmat_union, (1, 8, 900), RMAT(log_n=8, m=900, seed=1), 2),
+    (rmat.rmat_union, (1, 8, 900), RMAT(n=1 << 8, m=900, seed=1), 2),
 ], ids=lambda x: getattr(x, "__name__", ""))
 def test_shim_warns_and_matches_generate(shim, args, spec, P):
     with pytest.warns(DeprecationWarning, match="deprecated shim"):

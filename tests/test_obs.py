@@ -341,7 +341,7 @@ def test_generate_traced_has_all_three_phases():
     (GNM(n=64, m=100, seed=1), "plan/gnm"),
     (GNP(n=64, p=0.05, seed=1), "plan/gnp"),
     (BA(n=32, d=2, seed=1), "plan/ba"),
-    (RMAT(log_n=5, m=64, seed=1), "plan/rmat"),
+    (RMAT(n=1 << 5, m=64, seed=1), "plan/rmat"),
     (SBM(n=48, blocks=2, p_in=0.2, p_out=0.05, seed=1), "plan/sbm"),
 ])
 def test_every_family_opens_its_plan_span(spec, span):

@@ -19,7 +19,7 @@ def mixed_specs():
         GNM(n=128, m=400, directed=True, seed=13),
         GNP(n=100, p=0.06, seed=5),
         BA(n=90, d=2, seed=3),
-        RMAT(log_n=6, m=120, seed=9),
+        RMAT(n=1 << 6, m=120, seed=9),
         SBM(n=96, blocks=3, p_in=0.2, p_out=0.02, seed=4),
         RGG(n=80, radius=0.2, seed=2),
         RHG(n=70, avg_deg=4.0, gamma=2.7, seed=8),
@@ -101,7 +101,7 @@ def plans_equal(a, b):
     lambda s: GNM(n=128, m=300, directed=True, seed=s),
     lambda s: GNP(n=100, p=0.05, seed=s),
     lambda s: BA(n=90, d=2, seed=s),
-    lambda s: RMAT(log_n=6, m=120, seed=s),
+    lambda s: RMAT(n=1 << 6, m=120, seed=s),
     lambda s: SBM(n=96, blocks=3, p_in=0.2, p_out=0.02, seed=s),
     lambda s: RGG(n=80, radius=0.2, seed=s),
     lambda s: RHG(n=70, avg_deg=4.0, gamma=2.7, seed=s),

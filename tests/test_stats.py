@@ -174,7 +174,7 @@ def test_validate_rhg_tail_exponent_2_18():
     GNM(n=2048, m=8192, seed=5),
     BA(n=2048, d=4, seed=7),
     SBM(n=1500, blocks=5, p_in=0.03, p_out=0.003, seed=3),
-    RMAT(log_n=11, m=16000, seed=1),
+    RMAT(n=1 << 11, m=16000, seed=1),
 ], ids=lambda s: type(s).__name__)
 def test_validate_smoke_other_families(spec):
     rep = validate(spec, 4)
