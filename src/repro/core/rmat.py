@@ -12,11 +12,14 @@ labels are randomly permuted after generation.
 **The instance.**  With ``L = log2(n)`` and ``K = device_key(seed, 51)``
 (the chunk key every RMAT chunk carries), edge ``i`` of ``[0, m)``:
 
-1. *descent* (``rmat/descent``): ``L`` float64 uniforms
-   ``jax.random.uniform(fold_in64(K, i), (L,))``; level ``l`` (most
-   significant bit first) takes quadrant ``q = [u >= a] + [u >= a + b]
-   + [u >= a + b + c]``, setting bit ``L-1-l`` of the source to
-   ``q >= 2`` and of the target to ``q mod 2``;
+1. *descent* (``rmat/descent``): ``L`` 64-bit words ``w =
+   jax.random.bits(fold_in64(K, i), (L,), uint64)``; level ``l`` (most
+   significant bit first) takes quadrant ``q = [w >= T_0 << 12] + [w >=
+   T_1 << 12] + [w >= T_2 << 12]``, with ``T_j = ceil(t_j 2^52)`` of ``t
+   = a, a + b, a + b + c`` (float64 sums) computed on the host, setting
+   bit ``L-1-l`` of the source to ``q >= 2`` and of the target to ``q mod
+   2``.  This is float64's ``u >= t`` on ``jax.random.uniform``'s exact
+   ``u = (w >> 12) / 2^52``, since ``w >> 12 >= ceil(t 2^52)`` iff ``u >= t``;
 2. *permutation* (``rmat/permute``): both ends pass through ``pi``, a
    keyed bijection of ``[0, 2^L)``.  With ``Kp = fold_in(K, 52)`` and,
    for ``j = 0..3``, ``(h_j, l_j)`` the two key words of ``fold_in(Kp,
@@ -41,7 +44,9 @@ new seed compiles nothing new.
 """
 from __future__ import annotations
 
-from functools import partial
+import math
+from fractions import Fraction
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -89,36 +94,50 @@ def permute(x, consts, log_n: int):
     return y.astype(x.dtype)
 
 
-def descend(key, eid, a, b, c, log_n: int):
+def thresholds(probs) -> Tuple[int, int, int]:
+    """``T_j = ceil(t_j 2^52)`` of ``t = a, a + b, a + b + c`` (IEEE
+    float64 sums on the host, in exact rationals after that), clamped to
+    ``[0, 2^52]``: the static constants of the descent."""
+    a, b, c = (float(p) for p in probs[:3])
+    return tuple(min(max(math.ceil(Fraction(t) * 2**52), 0), 1 << 52)
+                 for t in (a, a + b, a + b + c))
+
+
+def _at_least(hi, lo, t: int):
+    """``(hi 2^32 + lo) >> 12 >= t`` for uint32 halves of a word and a
+    static ``t`` in ``[0, 2^52]``, compared on the halves."""
+    if t >= 1 << 52:
+        return jnp.zeros(hi.shape, bool)
+    th, tl = np.uint32(t >> 20), np.uint32((t & 0xFFFFF) << 12)
+    return (hi > th) | ((hi == th) & (lo >= tl))
+
+
+def descend(key, eid, thr: Tuple[int, int, int], log_n: int):
     """Unpermuted ``(src, dst)`` of edge ``eid`` (int64): the quadrant
-    descent of step 1, one ``fold_in64`` and ``log_n`` float64 uniforms."""
+    descent of step 1, one ``fold_in64`` and ``log_n`` 64-bit words
+    compared with the thresholds ``thr`` (:func:`thresholds`)."""
     k = fold_in64(key, eid)  # 64-bit safe: edge ids exceed 2^32 at scale
-    uu = jax.random.uniform(k, (log_n,), dtype=jnp.float64)
-    quad = (
-        (uu >= a).astype(jnp.int64)
-        + (uu >= a + b).astype(jnp.int64)
-        + (uu >= a + b + c).astype(jnp.int64)
-    )
+    w = jax.random.bits(k, (log_n,), jnp.uint64)
+    hi, lo = (w >> 32).astype(jnp.uint32), w.astype(jnp.uint32)
+    quad = sum(_at_least(hi, lo, t).astype(jnp.int32) for t in thr)
     bits = jnp.arange(log_n - 1, -1, -1, dtype=jnp.int64)
     src = jnp.sum((quad >= 2).astype(jnp.int64) << bits)
-    dst = jnp.sum((quad % 2) << bits)
+    # q & 1, not q % 2: an emulated int64 remainder is most of the cost
+    dst = jnp.sum((quad & 1).astype(jnp.int64) << bits)
     return src, dst
 
 
-def edges(key, eids, a, b, c, log_n: int):
+def edges(key, eids, thr: Tuple[int, int, int], log_n: int):
     """Permuted ``(src, dst)`` [k] int64 of the edges ``eids``: the
     instance of the module docstring, as the engine's RMAT chunks run it."""
     with jax.named_scope("rmat/descent"):
-        src, dst = jax.vmap(lambda e: descend(key, e, a, b, c, log_n))(eids)
+        src, dst = jax.vmap(lambda e: descend(key, e, thr, log_n))(eids)
     with jax.named_scope("rmat/permute"):
         consts = perm_constants(key, log_n)
         return permute(src, consts, log_n), permute(dst, consts, log_n)
 
 
-@partial(jax.jit, static_argnames=("log_n",))
-def _rmat_edges(key, edge_ids, probs, log_n: int):
-    a, b, c, _ = probs
-    return edges(key, edge_ids, a, b, c, log_n)
+_rmat_edges = jax.jit(edges, static_argnames=("thr", "log_n"))
 
 
 def rmat_pe(
@@ -133,7 +152,7 @@ def rmat_pe(
     elo, ehi = section_bounds(m, P, pe)
     key = device_key(seed, _TAG_RMAT)
     ids = jnp.arange(elo, ehi, dtype=jnp.int64)
-    src, dst = _rmat_edges(key, ids, jnp.array(probs, jnp.float64), log_n)
+    src, dst = _rmat_edges(key, ids, thresholds(probs), log_n)
     return np.stack([np.asarray(src), np.asarray(dst)], axis=1)
 
 

@@ -46,7 +46,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from ..core.prng import counter_uniform, fold_in64
-from ..core.rmat import edges as rmat_edges
+from ..core.rmat import edges as rmat_edges, thresholds as rmat_thresholds
 from ..core.sampling import (
     decode_directed,
     decode_rect,
@@ -166,6 +166,19 @@ class ChunkPlan:
         sel = self.kind == KIND_RMAT
         return int(self.params[sel, 0].max()) if sel.any() else 0
 
+    @property
+    def rmat_thresholds(self) -> Tuple[int, ...]:
+        """Static descent thresholds (:func:`repro.core.rmat.thresholds`)
+        of the one probability triple every RMAT chunk in the plan shares;
+        ``()`` without RMAT chunks."""
+        probs = self.fparams[self.kind == KIND_RMAT, :3]
+        if not len(probs):
+            return ()
+        if (probs != probs[0]).any():
+            raise ValueError("RMAT chunks of one plan must share their "
+                             "probabilities (a, b, c)")
+        return rmat_thresholds(probs[0])
+
     # ---- PlanProgram protocol (repro.distrib.runtime) ----
 
     def input_arrays(self) -> Tuple[np.ndarray, ...]:
@@ -173,7 +186,8 @@ class ChunkPlan:
 
     def slot_fn(self):
         return _edge_chunk_fn(self.capacity, self.rng_impl,
-                              self.kinds_present, self.rmat_log_n)
+                              self.kinds_present, self.rmat_log_n,
+                              self.rmat_thresholds)
 
     def stream_index(self) -> np.ndarray:
         return owned_chunk_index(self)
@@ -183,7 +197,7 @@ class ChunkPlan:
         # params, so plans differing only in n share one compiled program.
         return ("chunk", self.kind.shape, self.key_data.shape[-1],
                 self.capacity, self.rng_impl, self.kinds_present,
-                self.rmat_log_n)
+                self.rmat_log_n, self.rmat_thresholds)
 
     def reseed(self, seed: int) -> "ChunkPlan":
         """The plan this emitter would have produced for ``seed``.
@@ -363,12 +377,14 @@ def reseedable_chunk_plan(plan: ChunkPlan, key_fn: Callable[[int], np.ndarray],
 
 
 def _edge_chunk_fn(capacity: int, rng_impl: str,
-                   kinds: Sequence[int] = SAMPLED_KINDS, log_n: int = 0):
+                   kinds: Sequence[int] = SAMPLED_KINDS, log_n: int = 0,
+                   rmat_thr: Tuple[int, ...] = ()):
     """Per-chunk device program, specialized to the kinds in the plan.
 
     Sampled kinds (DIRECTED/TRI/RECT) share one without-replacement
     index draw + per-kind decode; RMAT runs the per-edge hashed quadrant
-    descent (one fold_in per edge id, ``log_n`` uniforms) and Graph 500's
+    descent (one fold_in per edge id, ``log_n`` 64-bit words compared
+    with the static integer thresholds ``rmat_thr``) and Graph 500's
     vertex permutation (:func:`repro.core.rmat.edges`); BA resolves
     the Batagelj-Brandes position chain with a hashed ``while_loop``
     (Sanders-Schulz).  Only the branches for kinds actually present are
@@ -402,8 +418,7 @@ def _edge_chunk_fn(capacity: int, rng_impl: str,
                 v = jnp.where(kind == KIND_RECT, rv, v)
 
         if KIND_RMAT in kinds:
-            ru, rv = rmat_edges(key, p1 + idx, fparams[0], fparams[1],
-                                fparams[2], log_n)
+            ru, rv = rmat_edges(key, p1 + idx, rmat_thr, log_n)
             u = jnp.where(kind == KIND_RMAT, ru, u)
             v = jnp.where(kind == KIND_RMAT, rv, v)
 

@@ -75,18 +75,20 @@ class SlabProgram:
     kinds: Tuple[int, ...]    # KIND_* / GEOM_* branches the program lowers
     dim: int = 2              # pair: spatial dimension (static decode)
     log_n: int = 0            # chunk: RMAT descent depth (0 = no RMAT branch)
+    rmat_thr: Tuple[int, ...] = ()  # chunk: RMAT descent thresholds
     K: int = 1                # pair: gid words
     G: int = 1                # pair: geometry features
     F: int = 1                # pair: float params
 
     def signature(self) -> tuple:
         return ("serve", self.plan_kind, self.capacity, self.W, self.rng_impl,
-                self.kinds, self.dim, self.log_n, self.K, self.G, self.F)
+                self.kinds, self.dim, self.log_n, self.rmat_thr, self.K,
+                self.G, self.F)
 
     def slot_fn(self):
         if self.plan_kind == "chunk":
             return engine._edge_chunk_fn(self.capacity, self.rng_impl,
-                                         self.kinds, self.log_n)
+                                         self.kinds, self.log_n, self.rmat_thr)
         return engine._pair_fn(self.capacity, self.rng_impl, self.kinds,
                                self.dim)
 
@@ -140,10 +142,11 @@ def program_of(plan) -> SlabProgram:
 
     Chunk plans of one capacity class share a program lowering all
     sampled kinds + BA (RMAT plans additionally key on their static
-    descent depth), so G(n,m)/G(n,p)/SBM/BA rows pack together.  Pair
-    plans without CERT rows share the HYP+TORUS program per (capacity
-    class, dim), so RGG and RHG rows pack together; CERT plans key on
-    their exact capacity (the emit bitmask is capacity-indexed).
+    descent depth and thresholds), so G(n,m)/G(n,p)/SBM/BA rows pack
+    together.  Pair plans without CERT rows share the HYP+TORUS program
+    per (capacity class, dim), so RGG and RHG rows pack together; CERT
+    plans key on their exact capacity (the emit bitmask is
+    capacity-indexed).
     """
     if isinstance(plan, engine.ChunkPlan):
         log_n = plan.rmat_log_n
@@ -151,7 +154,8 @@ def program_of(plan) -> SlabProgram:
                        | ({engine.KIND_RMAT} if log_n else set()))
         return SlabProgram("chunk", _capacity_class(plan.capacity, 64),
                            plan.key_data.shape[-1], plan.rng_impl,
-                           tuple(kinds), log_n=log_n)
+                           tuple(kinds), log_n=log_n,
+                           rmat_thr=plan.rmat_thresholds)
     if isinstance(plan, engine.PairPlan):
         W = plan.key_a.shape[-1]
         if engine.GEOM_CERT in plan.kinds_present:

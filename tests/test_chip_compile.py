@@ -100,3 +100,31 @@ def test_chunk_wave_step_compiles(topo):
         assert_communication_free(compiled)
         rows = (plan.capacity, 2) if squeeze else (1, plan.capacity, 2)
         assert compiled.out_info[0].shape == rows
+
+
+def test_rmat_wave_step_compiles(topo):
+    """One wave of the Graph 500 Toy stream (n = 2^26, m = 2^30, 1024
+    chunks of 2^20 edge ids, one-chip mesh, batch 1), collective-free.
+    The descent compares integers, with no emulated float64 and no int64
+    remainder per edge and level: XLA's cost model counts about 8.3 k
+    operations an edge and 0.5 MB of temporaries, and the bounds fail a
+    descent on float64 uniforms with ``q % 2`` (55 k, 437 MB)."""
+    from repro.analyze.hloscan import assert_communication_free
+    from repro.api import RMAT
+    from repro.distrib import runtime
+
+    plan = RMAT(n=1 << 26, m=1 << 30, seed=0, chunks=1024).plan(1024)
+    assert plan.capacity == 1 << 20
+    mesh = Mesh(np.array(topo.devices[:1]), ("pe",))
+    ns = NamedSharding(mesh, PartitionSpec("pe"))
+    tables = plan.input_arrays()
+    fn = runtime._wave_fn(plan, mesh, len(tables), True)
+    compiled = fn.lower(
+        _shape((1, 1, 2), jnp.int32, ns), _shape((1, 1), jnp.bool_, ns),
+        *(_shape(t.shape, t.dtype, ns) for t in tables)).compile()
+    assert_communication_free(compiled)
+    assert compiled.out_info[0].shape == (plan.capacity, 2)
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    assert cost["flops"] / plan.capacity < 20_000
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
